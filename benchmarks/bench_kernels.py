@@ -38,6 +38,9 @@ def _time(fn, *args, repeats=5):
 
 
 def main() -> dict:
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     p = SIZES[SCALE]
     M, F, T = p["M"], p["F"], p["T"]
     key = jax.random.PRNGKey(0)

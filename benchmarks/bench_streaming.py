@@ -400,4 +400,7 @@ def main(argv=None) -> list:
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -12,25 +12,21 @@ by ``benchmarks/report.py``.
 from __future__ import annotations
 
 import argparse
+import importlib
 import time
 import traceback
 
-from benchmarks import (
-    bench_kernels,
-    fig5_rows,
-    fig6_cols,
-    fig7_selected,
-    fig8_nodes,
-    fig9_encodings,
-)
-
+# Suite name -> module with a ``main()``.  Modules import lazily, in run
+# order: the fig suites start worker processes that each claim the
+# accelerator, so this process must not touch JAX before they finish.
+# ``kernels`` runs in this process and therefore comes last.
 SUITES = {
-    "fig5_rows": fig5_rows.main,
-    "fig6_cols": fig6_cols.main,
-    "fig7_selected": fig7_selected.main,
-    "fig8_nodes": fig8_nodes.main,
-    "fig9_encodings": fig9_encodings.main,
-    "kernels": bench_kernels.main,
+    "fig5_rows": "benchmarks.fig5_rows",
+    "fig6_cols": "benchmarks.fig6_cols",
+    "fig7_selected": "benchmarks.fig7_selected",
+    "fig8_nodes": "benchmarks.fig8_nodes",
+    "fig9_encodings": "benchmarks.fig9_encodings",
+    "kernels": "benchmarks.bench_kernels",
 }
 
 
@@ -40,12 +36,16 @@ def main() -> None:
                     help="comma-separated suite names (default: all)")
     args = ap.parse_args()
     names = args.only.split(",") if args.only else list(SUITES)
+    unknown = sorted(set(names) - set(SUITES))
+    if unknown:
+        raise SystemExit(f"unknown suites {unknown}; known: {list(SUITES)}")
+    names.sort(key=list(SUITES).index)  # in-process suites last
     failed = []
     print("name,us_per_call,derived")
     for name in names:
         t0 = time.time()
         try:
-            SUITES[name]()
+            importlib.import_module(SUITES[name]).main()
             print(f"# suite {name} done in {time.time()-t0:.0f}s", flush=True)
         except Exception:
             failed.append(name)

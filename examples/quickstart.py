@@ -143,7 +143,9 @@ with SelectionService(workers=2) as svc:
 # init_multihost() then MRMRSelector(..., hosts="auto"); here we drive
 # the spawn-mode launcher, which stands up a loopback 2-process cluster.
 # (Real cluster: one invocation per machine with --coordinator/--process-id.)
+# The loopback cluster runs on the CPU, so this process keeps its chip.
 import json
+import os
 import subprocess
 import sys
 
@@ -152,6 +154,7 @@ proc = subprocess.run(
      "--num-processes", "2", "--rows", "6000", "--cols", "24",
      "--select", "4", "--block-obs", "1500"],
     capture_output=True, text=True, check=True,
+    env=dict(os.environ, JAX_PLATFORMS="cpu"),
 )
 mh = json.loads(proc.stdout.splitlines()[-1])
 agg = mh["hosts"]["aggregate"]["bytes_read"]

@@ -287,7 +287,7 @@ Layers
   optimisation.
 * ``repro.dist``    — the distribution substrate: named meshes, logical
   sharding rules, multi-host map-reduce (``repro.dist.multihost``),
-  pipeline parallelism, jax version compat.
+  pipeline parallelism.
 * ``repro.kernels`` — Pallas TPU kernels for the scoring hot spots.
 * ``repro.models``  — architecture zoo (dense / MoE / SSM / hybrid /
   enc-dec / VLM backbones) used as workloads for the substrate.

@@ -55,8 +55,8 @@ from repro.core.scores import (
     cmi_from_counts,
     mi_from_counts,
 )
-from repro.dist import compat
 from repro.dist.sharding import axes_tuple as _axes_tuple
+from repro.dist.sharding import pvary
 
 Array = jax.Array
 
@@ -219,11 +219,6 @@ class WarmJitCache:
 # ---------------------------------------------------------------------------
 # helpers
 # ---------------------------------------------------------------------------
-
-def _pvary(x, axes: tuple):
-    """Mark ``x`` as varying over ``axes`` (shard_map VMA typing helper)."""
-    return compat.pvary(x, axes)
-
 
 def _flat_axis_index(axes: Sequence[str], mesh_axis_sizes: dict) -> Array:
     """Row-major flattened index of this shard along ``axes``."""
@@ -519,7 +514,7 @@ def make_conventional_fn(
     obs_axes = _axes_tuple(obs_axes)
     body = functools.partial(_conventional_body, obs_axes=obs_axes, **kwargs)
     return jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(P(obs_axes, None), P(obs_axes)),
@@ -563,9 +558,9 @@ def _alternative_body(
     state = _loop_state(n_loc, num_select)
     # mask and the criterion's fold state are per-shard slices -> varying
     # along the feature axes.
-    state["mask"] = _pvary(state["mask"], feat_axes)
+    state["mask"] = pvary(state["mask"], feat_axes)
     if use_incr and fold:
-        state["crit"] = _pvary(crit.init_state(n_loc), feat_axes)
+        state["crit"] = pvary(crit.init_state(n_loc), feat_axes)
     # The paper's broadcast v_s: replicated buffer of selected feature rows.
     state["sel_rows"] = jnp.zeros((num_select, m), jnp.float32)
 
@@ -579,14 +574,14 @@ def _alternative_body(
         if custom:
             g = score.full_score(X_loc, y, st["sel_rows"], l)
         elif not fold:
-            g = crit.objective(rel, _pvary(crit.init_state(n_loc), feat_axes), l)
+            g = crit.objective(rel, pvary(crit.init_state(n_loc), feat_axes), l)
         elif use_incr:
             g = crit.objective(rel, st["crit"], l)
         else:
             def inner(j, cs):
                 return crit.update(cs, red_terms(st["sel_rows"][j]), j)
 
-            cs0 = _pvary(crit.init_state(n_loc), feat_axes)
+            cs0 = pvary(crit.init_state(n_loc), feat_axes)
             cs = lax.fori_loop(0, l, inner, cs0)
             g = crit.objective(rel, cs, l)
         g = jnp.where(st["mask"] | ~valid, _NEG_INF, g)
@@ -665,7 +660,7 @@ def make_alternative_fn(
         _alternative_body, feat_axes=feat_axes, axis_sizes=axis_sizes, **kwargs
     )
     return jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(P(feat_axes, None), P()),
@@ -731,13 +726,13 @@ def _grid_body(
 
     rel = mi_from_counts(counts_vs(y_loc, c))
     state = _loop_state(n_loc, num_select)
-    state["mask"] = _pvary(state["mask"], feat_axes)
+    state["mask"] = pvary(state["mask"], feat_axes)
     if incremental and crit.needs_redundancy:
-        state["crit"] = _pvary(crit.init_state(n_loc), feat_axes)
+        state["crit"] = pvary(crit.init_state(n_loc), feat_axes)
 
     def body(l, st):
         if not crit.needs_redundancy:
-            g = crit.objective(rel, _pvary(crit.init_state(n_loc), feat_axes), l)
+            g = crit.objective(rel, pvary(crit.init_state(n_loc), feat_axes), l)
         elif incremental:
             g = crit.objective(rel, st["crit"], l)
         else:
@@ -745,7 +740,7 @@ def _grid_body(
                 xj = fetch_col(st["selected"][j])
                 return crit.update(cs, pair_terms(xj), j)
 
-            cs0 = _pvary(crit.init_state(n_loc), feat_axes)
+            cs0 = pvary(crit.init_state(n_loc), feat_axes)
             cs = lax.fori_loop(0, l, inner, cs0)
             g = crit.objective(rel, cs, l)
         g = jnp.where(st["mask"] | ~valid, _NEG_INF, g)
@@ -821,7 +816,7 @@ def make_grid_fn(
         incremental=incremental,
     )
     return jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(P(obs_axes, feat_axes), P(obs_axes)),

@@ -64,15 +64,17 @@ under every combination:
 Both of the paper's §III regimes stream:
 
 * **tall** — blocks shard over ``obs_axes`` (the paper's conventional
-  partitioning); statistics reduce with one all-reduce per block.
+  partitioning); statistics reduce with one ``psum`` per block.
 * **wide** — blocks *and the statistics state* shard over ``feat_axes``
   (the alternative/vertical partitioning), so the ``O(N · d_v · d_c)``
   per-pair state that would blow one device spreads across the mesh:
   per-device statistics memory is ``O(N/shards · d_v · d_c)`` (times
   ``q`` under batching).
-* **both-large** — a 2-D (obs × feat) grid combines the two; XLA
-  partitions the accumulate across the grid from the input/state
-  shardings alone.
+* **both-large** — a 2-D (obs × feat) grid combines the two.
+
+In every layout the per-block count runs under ``shard_map``
+(:func:`_per_shard_step`): each device counts its own slice with the
+Pallas kernel on TPU, which XLA could not partition on its own.
 
 ``prefetch`` double-buffers placement (:class:`~repro.dist.streaming.
 PrefetchPlacer`): the host reads/pads/``device_put``s block ``i+1`` while
@@ -88,6 +90,8 @@ math above is asserted by tests and benchmarks, not eyeballed.
 """
 
 from __future__ import annotations
+
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -161,25 +165,122 @@ def _cached_acc_fn(
             if batch is None
             else jax.vmap(score.accumulate, in_axes=(0, None, 0, None))
         )
-        if num_edges is None:
-            return jax.jit(step, out_shardings=shardings)
+        if num_edges is not None:
+            from repro.kernels import ops  # lazy: avoids core<->kernels cycle
 
-        from repro.kernels import ops  # lazy: avoids core<->kernels cycle
+            use_pallas = getattr(score, "use_pallas", "auto")
+            count = step
 
-        use_pallas = getattr(score, "use_pallas", "auto")
+            # Fused binned accumulate: the raw float block encodes to bin
+            # codes on device (Pallas/jnp searchsorted) feeding straight
+            # into the one-hot contingency sum — no int block round-trips
+            # through host memory.  Edges ride as a traced argument, so
+            # this compiles once per geometry, not per fitted-edge content.
+            def step(state, X_block, target, valid, edges):
+                codes = ops.bin_codes(X_block, edges, use_pallas=use_pallas)
+                return count(state, codes, target, valid)
 
-        # Fused binned accumulate: the raw float block encodes to bin codes
-        # on device (Pallas/jnp searchsorted) feeding straight into the
-        # one-hot contingency sum — no int block round-trips through host
-        # memory.  Edges ride as a traced argument, so this compiles once
-        # per geometry, not per fitted-edge content.
-        def fused(state, X_block, target, valid, edges):
-            codes = ops.bin_codes(X_block, edges, use_pallas=use_pallas)
-            return step(state, codes, target, valid)
+        if mesh is not None and score.supports_state_merge:
+            step = _per_shard_step(step, placer, shardings, batch, num_edges)
+        return jax.jit(step, out_shardings=shardings)
 
-        return jax.jit(fused, out_shardings=shardings)
+    fn = _ACC_FN_CACHE.get_or_build(key, build)
+    return _ACC_LOGS[-1]._wrap(fn) if _ACC_LOGS else fn
 
-    return _ACC_FN_CACHE.get_or_build(key, build)
+
+# Active ``AccumulateLog``s, innermost last.
+_ACC_LOGS: list = []
+
+
+class LoggedAccumulate(NamedTuple):
+    fn: Any  # the jitted accumulate
+    args: tuple  # jax.ShapeDtypeStruct pytree of its first call
+    output: Any  # the state that call returned
+
+
+class AccumulateLog:
+    """Records the accumulate programs streamed fits run while it is open.
+
+    ``with AccumulateLog() as log: selector.fit(source)`` keeps one entry
+    per distinct program and argument layout the fit ran: the jitted
+    accumulate, its abstract arguments (shape, dtype, sharding) and the
+    first state it returned.  So a check of what ran — ``compiled_texts()``
+    for a ``tpu_custom_call``, ``entries[i].output.addressable_shards`` for
+    the state layout — sees the engine's own programs, not a rebuild.
+    Process-wide while open; costs a few microseconds of host time per
+    block, nothing when no log is open.
+    """
+
+    def __init__(self):
+        self._entries: dict = {}
+
+    @property
+    def entries(self) -> list[LoggedAccumulate]:
+        """One per distinct program and layout, in the order first run."""
+        return list(self._entries.values())
+
+    def compiled_texts(self) -> list[str]:
+        """Optimized HLO of every recorded program.  Each is compiled
+        again, which the persistent compile cache serves where it is on."""
+        return [
+            fn.lower(*args).compile().as_text() for fn, args, _ in self.entries
+        ]
+
+    def _wrap(self, fn):
+        def logged(*args):
+            out = fn(*args)
+            leaves, tree = jax.tree.flatten(args)
+            sig = (id(fn), tree, tuple((a.shape, a.dtype) for a in leaves))
+            if sig not in self._entries:
+                abstract = jax.tree.map(
+                    lambda a: jax.ShapeDtypeStruct(
+                        a.shape, a.dtype, sharding=a.sharding
+                    ),
+                    args,
+                )
+                self._entries[sig] = LoggedAccumulate(fn, abstract, out)
+            return out
+
+        return logged
+
+    def __enter__(self) -> "AccumulateLog":
+        _ACC_LOGS.append(self)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        _ACC_LOGS.remove(self)
+
+
+def _per_shard_step(step, placer: BlockPlacer, shardings, batch, num_edges):
+    """Run ``step`` on each device's own slice of the block.
+
+    Pallas kernels cannot be partitioned by XLA, so the count runs under
+    ``shard_map``: every device counts its row/column slice into a zero
+    state, the obs-sharded layouts ``psum`` those counts over the
+    observation axes (once per block), and the sum lands on the state in
+    its placer layout.  Only scores whose state merges by plain addition
+    (``supports_state_merge``) take this path; the rest are pure jnp and
+    left to XLA's partitioner."""
+    obs, feat = placer.obs_axes or None, placer.feat_axes or None
+    in_specs = (
+        jax.tree.map(lambda s: s.spec, shardings),
+        P(obs, feat),
+        P(obs) if batch is None else P(None, obs),
+        P(obs),
+    ) + (() if num_edges is None else (P(feat, None),))
+
+    def local(state, *block):
+        delta = step(jax.tree.map(jnp.zeros_like, state), *block)
+        if obs:
+            delta = jax.lax.psum(delta, obs)
+        return jax.tree.map(jnp.add, state, delta)
+
+    # check_vma=False: interpret-mode Pallas (the CPU test harness) cannot
+    # type varying axes; the specs above pin the layout either way.
+    return jax.shard_map(
+        local, mesh=placer.mesh, in_specs=in_specs, out_specs=in_specs[0],
+        check_vma=False,
+    )
 
 
 def _placed_edges(edges: np.ndarray, placer: BlockPlacer):

@@ -3,14 +3,13 @@
 Everything that touches device topology lives here, so the rest of the
 repo (drivers, models, launchers) never talks to raw jax device state:
 
-* ``repro.dist.compat``   — version-portable ``shard_map`` / ``pvary``
-  (jax moved both across releases; call sites import from here).
 * ``repro.dist.meshes``   — ``make_mesh``: named device meshes from a
   (shape, axis-names) pair, the single mesh constructor in the repo.
 * ``repro.dist.sharding`` — logical-axis sharding: ``ShardingRules`` maps
   logical parameter axes (``fsdp``, ``ff``, ``heads``, ...) to mesh axes,
   ``logical_to_spec`` resolves them to ``PartitionSpec`` with divisibility
-  and axis-reuse guards.
+  and axis-reuse guards; ``pvary`` marks values varying inside
+  ``shard_map``.
 * ``repro.dist.pipeline`` — GPipe pipeline parallelism over a mesh axis.
 * ``repro.dist.streaming`` — ``BlockPlacer``: pad-and-shard placement of
   streamed observation-blocks (obs-sharded, feature-sharded or 2-D grid)
@@ -23,7 +22,6 @@ repo (drivers, models, launchers) never talks to raw jax device state:
   ``shard_map``-ped psums over a one-device-per-process mesh).
 """
 
-from repro.dist.compat import pvary, shard_map  # noqa: F401
 from repro.dist.meshes import factor_mesh, host_mesh, make_mesh  # noqa: F401
 from repro.dist.multihost import (  # noqa: F401
     HostCollectives,
@@ -38,5 +36,6 @@ from repro.dist.sharding import (  # noqa: F401
     axes_tuple,
     logical_to_spec,
     mesh_extent,
+    pvary,
     rules_for,
 )

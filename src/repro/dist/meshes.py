@@ -13,7 +13,7 @@ from typing import Sequence
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_mesh(
@@ -42,9 +42,11 @@ def make_mesh(
             f"have {len(devices)}"
         )
     devices = list(devices)[:n]
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, axes, devices=devices)
-    return Mesh(np.asarray(devices).reshape(shape), axes)
+    # Auto axes: the placer and engines hand XLA input/output shardings and
+    # let it partition the rest (jax.make_mesh defaults to Explicit).
+    return jax.make_mesh(
+        shape, axes, devices=devices, axis_types=(AxisType.Auto,) * len(axes)
+    )
 
 
 def host_mesh(

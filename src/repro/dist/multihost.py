@@ -50,7 +50,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.dist.compat import shard_map
 from repro.dist.meshes import factor_mesh, host_mesh
 
 _OBS_AXIS, _FEAT_AXIS = "oh", "fh"  # host-mesh axis names (obs / feature)
@@ -120,11 +119,8 @@ def init_multihost(
             f"and process_id (got coordinator={coordinator!r}, "
             f"num_processes={num_processes!r}, process_id={process_id!r})"
         )
-    try:
-        # Only affects the CPU backend; must land before backend init.
-        jax.config.update("jax_cpu_collectives_implementation", cpu_collectives)
-    except AttributeError:  # jax without the knob: single-impl build
-        pass
+    # Only affects the CPU backend; must land before backend init.
+    jax.config.update("jax_cpu_collectives_implementation", cpu_collectives)
     try:
         jax.distributed.initialize(
             coordinator_address=coordinator,
@@ -361,7 +357,7 @@ class HostCollectives:
                 return tuple(jax.lax.psum(x, axes) for x in xs)
 
             fn = jax.jit(
-                shard_map(
+                jax.shard_map(
                     merge,
                     mesh=self._mesh,
                     in_specs=(in_spec,) * n,

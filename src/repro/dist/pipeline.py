@@ -14,7 +14,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.dist import compat
+from repro.dist.sharding import pvary
 
 Array = jax.Array
 
@@ -63,8 +63,8 @@ def pipeline_apply(
                 h = stage_fn(jax.tree.map(lambda a: a[j], p_loc), h)
             return h
 
-        carry = compat.pvary(jnp.zeros_like(mbs[0]), (axis,))
-        out = compat.pvary(jnp.zeros_like(mbs), (axis,))
+        carry = pvary(jnp.zeros_like(mbs[0]), (axis,))
+        out = pvary(jnp.zeros_like(mbs), (axis,))
         last = n_stages - 1
         for t in range(microbatches + n_stages - 1):
             feed = mbs[min(t, microbatches - 1)]
@@ -77,7 +77,7 @@ def pipeline_apply(
         out = lax.psum(out, axis)
         return out.reshape((b,) + x_rep.shape[1:])
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(

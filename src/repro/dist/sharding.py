@@ -13,6 +13,8 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
+from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 # mesh axis name, tuple of names (sharded over their product), or None
@@ -72,6 +74,14 @@ def axes_tuple(axes) -> tuple:
     if isinstance(axes, (list, tuple)):
         return tuple(axes)
     return (axes,)
+
+
+def pvary(x, axes):
+    """Mark every leaf of ``x`` as varying over ``axes`` inside
+    ``shard_map`` (identity for no axes)."""
+    if not axes:
+        return x
+    return jax.tree.map(lambda v: lax.pcast(v, tuple(axes), to="varying"), x)
 
 
 def mesh_extent(mesh: Mesh | None, axes) -> int:

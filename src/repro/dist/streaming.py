@@ -7,15 +7,15 @@ fixed row count (so the engine's accumulate step compiles exactly once)
 and, given a mesh, lands the block sharded per the plan's regime:
 
 * **obs-sharded** (tall datasets) — rows split over ``obs_axes``, each
-  device accumulating statistics for every feature on its row slice; XLA
-  reduces with the same all-reduce the in-memory conventional engine uses.
+  device accumulating statistics for every feature on its row slice; the
+  engine's ``shard_map``-ped accumulate sums them with one ``psum`` per block.
 * **feature-sharded** (wide datasets) — columns split over ``feat_axes``
   and the *statistics state itself* lives sharded over features
   (``place_state`` / ``state_shardings``), so per-device statistics memory
   is ``O(N/shards · d_v · d_c)`` instead of the full per-pair state.
 * **2-D grid** — both at once: rows over ``obs_axes``, columns and state
-  over ``feat_axes``; XLA partitions the accumulate across the grid and
-  all-reduces over the observation axes only.
+  over ``feat_axes``; each device counts its (rows, columns) tile and the
+  counts reduce over the observation axes only.
 
 Padded rows are reported through a ``valid`` mask; what a score does with
 it (out-of-range categories, zero-weighted moments) is the score's

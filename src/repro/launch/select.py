@@ -275,4 +275,7 @@ def main(argv=None) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -1,8 +1,10 @@
 """Multi-host feature selection — one process per shard, loopback or real.
 
 Spawn mode (the default) stands up an N-process ``jax.distributed``
-cluster on this machine — a free loopback coordinator port, N child
-copies of this script, gloo CPU collectives — runs the SAME selection in
+cluster on this machine's CPU — a free loopback coordinator port, N child
+copies of this script pinned to ``JAX_PLATFORMS=cpu``, gloo collectives;
+it refuses any other platform, since N processes on one host would each
+claim every accelerator chip — runs the SAME selection in
 every process with ``MRMRSelector(hosts=N)``, asserts every host
 committed the identical picks/gains, and prints one merged JSON report:
 
@@ -156,10 +158,20 @@ def _run_worker(args) -> dict:
 
 
 def _spawn(args, argv) -> dict:
+    # The loopback cluster is a CPU cluster (gloo collectives): N children
+    # on one machine would otherwise each claim every accelerator chip.
+    platforms = os.environ.get("JAX_PLATFORMS") or "cpu"
+    if platforms != "cpu":
+        raise SystemExit(
+            f"spawn mode runs its {args.num_processes} processes on the CPU "
+            f"of this machine, but JAX_PLATFORMS={platforms!r}: one process "
+            "per accelerator needs worker mode (--coordinator, "
+            "--num-processes, --process-id) on each host"
+        )
     coordinator = args.coordinator or f"127.0.0.1:{_free_port()}"
     procs = []
     for pid in range(args.num_processes):
-        env = dict(os.environ)
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         # Children resolve their place from argv, not env — scrub any
         # inherited multihost env so a nested launch can't cross wires.
         for k in ("REPRO_COORDINATOR", "REPRO_NUM_PROCESSES",
@@ -236,4 +248,7 @@ def main(argv=None) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

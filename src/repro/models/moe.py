@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.dist import compat
 from repro.models.layers import ParamDef
 
 Array = jax.Array
@@ -236,7 +235,7 @@ def moe_apply(
         },
     )
     out_specs = (P(batch_axes, model_axis, None), P())
-    fn = compat.shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
     pl = {key: p[key] for key in in_specs[1]}
     y, aux = fn(x, pl)
     return y, aux

@@ -12,7 +12,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro.dist import compat  # noqa: E402
+from repro.dist.sharding import pvary  # noqa: E402
 from repro.dist.meshes import make_mesh  # noqa: E402
 from repro.train.compression import (  # noqa: E402
     GradCompression,
@@ -35,7 +35,7 @@ def main() -> None:
         return out["g"], new.residual["g"][None]
 
     fn = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             body, mesh=mesh,
             in_specs=(P("data", None), P("data", None)),
             out_specs=(P(), P("data", None)),
@@ -61,7 +61,7 @@ def main() -> None:
             Xl, yl = Xl[0], yl[0]
             w = jnp.zeros((64,))
             # the error-feedback residual is per-shard state (VMA: varying)
-            r = compat.pvary(jnp.zeros((64,)), ("data",))
+            r = pvary(jnp.zeros((64,)), ("data",))
 
             def step(carry, _):
                 w, r = carry
@@ -80,7 +80,7 @@ def main() -> None:
             return w
 
         fn = jax.jit(
-            compat.shard_map(
+            jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P("data", None, None), P("data", None)),
                 out_specs=P(),

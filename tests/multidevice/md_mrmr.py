@@ -26,6 +26,7 @@ from repro.core import (  # noqa: E402
     mrmr_reference,
 )
 from repro.data.synthetic import corral_dataset  # noqa: E402
+from repro.dist.meshes import make_mesh  # noqa: E402
 
 
 def main() -> None:
@@ -43,7 +44,7 @@ def main() -> None:
     ref_sel = np.asarray(ref.selected)
 
     # --- conventional encoding over 8-way observation sharding ------------
-    mesh8 = jax.make_mesh((8,), ("data",))
+    mesh8 = make_mesh((8,), ("data",))
     conv = mrmr_conventional(
         jnp.asarray(X), jnp.asarray(y), L, score, mesh=mesh8, obs_axes=("data",)
     )
@@ -52,7 +53,7 @@ def main() -> None:
     print("conventional 8-way: OK")
 
     # --- conventional over a 2-axis (pod, data) product --------------------
-    mesh_pd = jax.make_mesh((2, 4), ("pod", "data"))
+    mesh_pd = make_mesh((2, 4), ("pod", "data"))
     conv2 = mrmr_conventional(
         jnp.asarray(X), jnp.asarray(y), L, score,
         mesh=mesh_pd, obs_axes=("pod", "data"),
@@ -61,7 +62,7 @@ def main() -> None:
     print("conventional (pod,data): OK")
 
     # --- alternative encoding over 8-way feature sharding ------------------
-    mesh_m = jax.make_mesh((8,), ("model",))
+    mesh_m = make_mesh((8,), ("model",))
     alt = mrmr_alternative(
         jnp.asarray(X.T), jnp.asarray(y), L, score,
         mesh=mesh_m, feat_axes=("model",),
@@ -79,7 +80,7 @@ def main() -> None:
     print("alternative padded: OK")
 
     # --- grid encoding: observations x features ----------------------------
-    mesh_g = jax.make_mesh((4, 2), ("data", "model"))
+    mesh_g = make_mesh((4, 2), ("data", "model"))
     grid = mrmr_grid(
         jnp.asarray(X), jnp.asarray(y), L, score,
         mesh=mesh_g, obs_axes=("data",), feat_axes=("model",),

@@ -6,7 +6,6 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.dist import compat
 from repro.train.compression import GradCompression, compressed_psum
 
 
@@ -60,7 +59,7 @@ def test_compressed_psum_matches_mean():
         return out["g"]
 
     fn = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             body, mesh=mesh, in_specs=P("data", None), out_specs=P()
         )
     )

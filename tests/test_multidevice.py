@@ -5,6 +5,7 @@ reserved for the dry-run), so every multi-device test runs in a child
 process with ``--xla_force_host_platform_device_count=8``.
 """
 
+import os
 import pathlib
 import subprocess
 import sys
@@ -20,8 +21,7 @@ def _run(script: str) -> None:
         [sys.executable, str(_HERE / "multidevice" / script)],
         capture_output=True,
         text=True,
-        env={"PYTHONPATH": _SRC, "PATH": "/usr/bin:/bin:/usr/local/bin",
-             "HOME": "/root"},
+        env={**os.environ, "PYTHONPATH": _SRC, "JAX_PLATFORMS": "cpu"},
         timeout=900,
     )
     if proc.returncode != 0:

@@ -391,6 +391,42 @@ class TestStreamingPrimitives:
         shard_rows = {s.data.shape[0] for s in state.addressable_shards}
         assert shard_rows == {placer.padded_features // n_dev}
 
+    @pytest.mark.parametrize(
+        "criterion,programs,state_shapes",
+        [
+            ("mid", 1, [(24, 2, 2)]),
+            # jmi's redundancy passes fuse the class into the pair target.
+            ("jmi", 2, [(24, 2, 2), (24, 2, 4)]),
+        ],
+    )
+    def test_accumulate_log_records_what_the_fit_ran(
+        self, corral, criterion, programs, state_shapes
+    ):
+        from repro.core.streaming import AccumulateLog
+
+        X, y = corral
+        with AccumulateLog() as log:
+            MRMRSelector(
+                num_select=3, score=MIScore(2, 2), criterion=criterion,
+                block_obs=512,
+            ).fit(ArraySource(X, y))
+        assert [e.args[0].shape for e in log.entries] == state_shapes
+        texts = log.compiled_texts()
+        assert len(texts) == programs and all("HloModule" in t for t in texts)
+        assert log.entries[0].output.shape == state_shapes[0]
+
+    def test_accumulate_log_off_outside_with(self, corral):
+        from repro.core.streaming import AccumulateLog
+
+        X, y = corral
+        log = AccumulateLog()
+        with log:
+            pass
+        MRMRSelector(num_select=2, score=MIScore(2, 2), block_obs=512).fit(
+            ArraySource(X, y)
+        )
+        assert log.entries == []
+
 
 @pytest.fixture(scope="module")
 def wide():
