@@ -87,7 +87,9 @@ source spills its *int codes*, so quantile-encode is also paid once.
 ``readahead=`` starts reading the next pass's blocks before the current
 pass drains (block reads never depend on the just-picked column).  Every
 streamed ``MRMRResult`` carries the measured ``io`` ledger, so the pass
-math is asserted by tests and benchmarks, not eyeballed.  (CLI: ``python
+math is asserted by tests and benchmarks, not eyeballed; under a
+``jax.profiler`` trace each layer of the fit shows as a ``mrmr.*`` span
+(:mod:`repro.runtime.tracing`).  (CLI: ``python
 -m repro.launch.select --batch-candidates 8 --spill-dir /tmp/spill
 --readahead 2``.)
 

@@ -44,6 +44,7 @@ from repro.data.sources import ArraySource, DataSource
 from repro.dist.meshes import factor_mesh, make_mesh
 from repro.dist.sharding import axes_tuple as _axes_tuple, mesh_extent
 from repro.dist.streaming import effective_block_obs, resolve_prefetch
+from repro.runtime import tracing
 
 Array = jax.Array
 
@@ -907,6 +908,17 @@ class MRMRSelector:
         return np.flatnonzero(mask) if indices else mask
 
     def _fit_source(self, source: DataSource) -> "MRMRSelector":
+        with tracing.fit_span() as fit:
+            with tracing.span(tracing.PLAN, fit=fit):
+                source, plan, mesh = self._plan_source(source)
+            engine = get_engine("streaming")
+            res = engine(source, None, num_select=self.num_select, plan=plan,
+                         mesh=mesh)
+            return self._finish_fit(res, plan, mesh, source.num_features)
+
+    def _plan_source(self, source: DataSource):
+        """-> ``(source, plan, mesh)`` of a source fit: the source as the
+        engine streams it (binned where asked), its score, plan and mesh."""
         if self.encoding not in ("auto", "streaming"):
             raise ValueError(
                 f"encoding {self.encoding!r} needs in-memory arrays; "
@@ -933,11 +945,7 @@ class MRMRSelector:
         plan = self._resolve_stream_plan(source, score)
         if isinstance(source, BinnedSource):
             plan = dataclasses.replace(plan, bins=source.bins)
-        mesh = self._resolve_mesh(plan)
-        engine = get_engine("streaming")
-        res = engine(source, None, num_select=self.num_select, plan=plan,
-                     mesh=mesh)
-        return self._finish_fit(res, plan, mesh, source.num_features)
+        return source, plan, self._resolve_mesh(plan)
 
     def fit(self, X, y=None) -> "MRMRSelector":
         """X: (observations, features) array + y: (observations,) targets,

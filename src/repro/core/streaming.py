@@ -86,11 +86,17 @@ dispatch; on elsewhere).
 Every fit reports its I/O on the result: ``MRMRResult.io`` carries
 ``passes`` / ``blocks_read`` / ``bytes_read`` counters (plus the spill
 cache's parse-vs-replay split when ``spill_dir`` is set), so the pass
-math above is asserted by tests and benchmarks, not eyeballed.
+math above is asserted by tests and benchmarks, not eyeballed.  It also
+counts the host round trips: ``host_syncs`` (device-to-host copies, one
+per finalize term and one per pick's objective) and ``h2d_bytes`` (host
+arrays placed on the device: every block triple, plus the vectors the
+greedy loop folds).  Each layer boundary is a ``mrmr.*`` profiler span
+(:mod:`repro.runtime.tracing`).
 """
 
 from __future__ import annotations
 
+import itertools
 from typing import Any, NamedTuple
 
 import jax
@@ -114,6 +120,7 @@ from repro.dist.streaming import (
     PrefetchPlacer,
     resolve_prefetch,
 )
+from repro.runtime import tracing
 
 _NEG_INF = float("-inf")
 
@@ -355,13 +362,19 @@ def _extract_target(
 class _PassIO:
     """Per-fit I/O ledger: every pass/block/byte the engine consumes,
     plus the peak statistics-state footprint (``state_bytes`` — how the
-    conditional-criterion memory tax is asserted, not eyeballed)."""
+    conditional-criterion memory tax is asserted, not eyeballed), and the
+    host round trips: device-to-host copies (``host_syncs``) and bytes of
+    host arrays placed on the device (``h2d_bytes``).  ``fit`` is the id
+    the fit's spans carry."""
 
     def __init__(self):
+        self.fit = tracing.fit_id()
         self.passes = 0
         self.blocks_read = 0
         self.bytes_read = 0
         self.state_bytes = 0
+        self.host_syncs = 0
+        self.h2d_bytes = 0
 
     def count(self, raw_blocks):
         for X_blk, y_blk in raw_blocks:
@@ -373,12 +386,27 @@ class _PassIO:
         size = sum(leaf.nbytes for leaf in jax.tree.leaves(state))
         self.state_bytes = max(self.state_bytes, size)
 
+    def note_placed(self, arrays):
+        self.h2d_bytes += sum(a.nbytes for a in arrays)
+
+    def to_device(self, tree):
+        """Host arrays -> device arrays, their bytes counted."""
+        self.note_placed(jax.tree.leaves(tree))
+        return jax.tree.map(jnp.asarray, tree)
+
+    def to_host(self, x) -> np.ndarray:
+        """A device array copied to a fresh float32 host array: one sync."""
+        self.host_syncs += 1
+        return np.array(x, np.float32)
+
     def as_dict(self) -> dict:
         return dict(
             passes=self.passes,
             blocks_read=self.blocks_read,
             bytes_read=self.bytes_read,
             state_bytes=self.state_bytes,
+            host_syncs=self.host_syncs,
+            h2d_bytes=self.h2d_bytes,
         )
 
 
@@ -413,6 +441,7 @@ def _score_pass(
     overrides how many leading feature rows survive the padding slice
     (default: the source's full width; a column-sharded host keeps only
     its own columns, dropping appended target columns too)."""
+    ids = {"fit": io.fit, "pass": io.passes}
     io.passes += 1
     binner = binned.binner if binned is not None else None
     cond = conditional and target_cols is not None
@@ -421,64 +450,113 @@ def _score_pass(
         if target_cols is None
         else ("feature_cond" if cond else "feature")
     )
-    if batch is None:
-        state = score.init_state(placer.padded_features, kind)
-    else:
-        state = jax.tree.map(
-            lambda leaf: jnp.zeros(
-                (batch,) + jnp.asarray(leaf).shape, jnp.asarray(leaf).dtype
-            ),
-            score.init_state(placer.padded_features, kind),
-        )
-    state = placer.place_state(state)
-    io.note_state(state)
-    cond_classes = score.num_classes if cond else None
-
-    def host_blocks():
-        for X_blk, y_blk in io.count(raw_pass):
-            if binner is not None:
-                X_blk = np.asarray(X_blk, np.float32)
-            yield X_blk, _extract_target(
-                X_blk, y_blk, target_cols, binner, cond_classes
-            )
-
-    if prefetch > 0:
-        placed = PrefetchPlacer(placer, depth=prefetch).stream(host_blocks())
-    else:
-        placed = (placer(X_blk, tgt) for X_blk, tgt in host_blocks())
-    for triple in placed:
-        state = acc_fn(state, *triple)
-    if merge_state is not None:
-        state = merge_state(state)
-    # Drop feature-padding columns on every read.
-    n = source.num_features if keep is None else int(keep)
-    if cond:
-        fin = (
-            score.finalize_conditional
-            if batch is None
-            else jax.vmap(score.finalize_conditional)
-        )
-        terms = {k: np.asarray(v, np.float32) for k, v in fin(state).items()}
+    with tracing.span(tracing.PASS, kind=kind, batch=batch or 1, **ids):
         if batch is None:
-            return {k: v[:n] for k, v in terms.items()}
-        return {k: v[:, :n] for k, v in terms.items()}
-    if batch is None:
-        scores = np.asarray(score.finalize(state), np.float32)
-        return scores[:n]
-    scores = np.asarray(jax.vmap(score.finalize)(state), np.float32)
-    return scores[:, :n]
+            state = score.init_state(placer.padded_features, kind)
+        else:
+            state = jax.tree.map(
+                lambda leaf: jnp.zeros(
+                    (batch,) + jnp.asarray(leaf).shape, jnp.asarray(leaf).dtype
+                ),
+                score.init_state(placer.padded_features, kind),
+            )
+        state = placer.place_state(state)
+        io.note_state(state)
+        cond_classes = score.num_classes if cond else None
+
+        def staged_blocks():
+            for block, (X_blk, y_blk) in enumerate(io.count(raw_pass)):
+                with tracing.span(tracing.STAGE, block=block, **ids):
+                    if binner is not None:
+                        X_blk = np.asarray(X_blk, np.float32)
+                    target = _extract_target(
+                        X_blk, y_blk, target_cols, binner, cond_classes
+                    )
+                    staged = placer.stage(X_blk, target)
+                yield staged
+
+        if prefetch > 0:
+            placed = PrefetchPlacer(placer, depth=prefetch).stream(
+                staged_blocks(), **ids
+            )
+        else:
+            placed = (
+                placer.place(staged, block=block, **ids)
+                for block, staged in enumerate(staged_blocks())
+            )
+        for block, triple in enumerate(placed):
+            io.note_placed(triple)
+            with tracing.span(tracing.ACCUMULATE, block=block, **ids):
+                state = acc_fn(state, *triple)
+        if merge_state is not None:
+            state = merge_state(state)
+        # Drop feature-padding columns on every read.
+        n = source.num_features if keep is None else int(keep)
+        with tracing.span(tracing.FINALIZE, **ids):
+            if cond:
+                fin = (
+                    score.finalize_conditional
+                    if batch is None
+                    else jax.vmap(score.finalize_conditional)
+                )
+                terms = {k: io.to_host(v) for k, v in fin(state).items()}
+                if batch is None:
+                    return {k: v[:n] for k, v in terms.items()}
+                return {k: v[:, :n] for k, v in terms.items()}
+            if batch is None:
+                return io.to_host(score.finalize(state))[:n]
+            return io.to_host(jax.vmap(score.finalize)(state))[:, :n]
 
 
-def _greedy_select(run_pass, crit: Criterion, n: int, num_select: int, q: int):
+def _pass_reader(
+    block_src: DataSource,
+    block_obs: int,
+    io: _PassIO,
+    readahead: int,
+    num_select: int,
+    crit: Criterion,
+):
+    """-> ``(next_raw, reader)``: ``next_raw()`` gives the next pass's raw
+    block iterator, each read in a ``mrmr.read`` span.  With ``readahead``
+    the reads run on a :class:`~repro.dist.streaming.CrossPassReader`
+    thread (returned, for the caller to close), else where the pass
+    iterates."""
+    pass_ids = itertools.count()
+
+    def read_pass():
+        return tracing.traced_reads(
+            block_src.iter_blocks(block_obs), block_src.num_obs,
+            fit=io.fit, **{"pass": next(pass_ids)},
+        )
+
+    if readahead <= 0:
+        return read_pass, None
+    # Upper bound on passes; batching/speculation only lowers it, and
+    # close() stops the reader thread wherever the fit actually ends.
+    reader = CrossPassReader(
+        read_pass,
+        depth=readahead,
+        max_passes=num_select if crit.needs_redundancy else 1,
+    )
+    return (
+        lambda: reader.next_pass(fit=io.fit, **{"pass": io.passes})
+    ), reader
+
+
+def _greedy_select(
+    run_pass, crit: Criterion, n: int, num_select: int, q: int, io: _PassIO
+):
     """The host-driven greedy loop shared by the single- and multi-host
     fits: one relevance pass, then exact per-pick criterion folds with
     ``q``-wide redundancy speculation.  ``run_pass(target_cols, batch=)``
     hides where blocks come from and how per-host statistics merge — by
     the time a vector reaches this loop every participating host holds
     the identical full-width copy, so every host commits the identical
-    pick with no designated master."""
+    pick with no designated master.  Each pick is one ``mrmr.pick`` span
+    that leaves out the pass it calls: a pick's redundancy is folded at
+    the start of the next pick."""
     rel = run_pass(None)
-    rel_j = jnp.asarray(rel)
+    rel_j = io.to_device(rel)
     cstate = crit.init_state(n)
     mask = np.zeros((n,), bool)
     selected = np.full((num_select,), -1, np.int32)
@@ -487,29 +565,29 @@ def _greedy_select(run_pass, crit: Criterion, n: int, num_select: int, q: int):
     # pairwise property of the data, so once computed it stays valid
     # for the whole fit (an in-batch pick never invalidates it).
     pending: dict = {}
+    red = None  # the last pick's redundancy terms, not yet folded
     for l in range(num_select):
-        # The criterion fold is the same pure-f32 jnp math the device
-        # drivers trace, so argmax ties resolve identically to the
-        # in-memory engines (toward the lowest id).
-        g = np.array(crit.objective(rel_j, cstate, l), np.float32)
-        g[mask] = _NEG_INF
-        k = int(np.argmax(g))
-        selected[l], gains[l] = k, g[k]
-        mask[k] = True
-        if l + 1 >= num_select or not crit.needs_redundancy:
-            continue
-        if k in pending:
-            red = pending.pop(k)  # speculation hit: zero I/O
-        else:
-            if q == 1:
-                red = run_pass(k)
-            else:
+        with tracing.span(tracing.PICK, fit=io.fit, pick=l):
+            if red is not None:
+                cstate = crit.update(cstate, io.to_device(red), l - 1)
+                red = None
+            # The criterion fold is the same pure-f32 jnp math the
+            # in-memory engines trace on the device, so argmax ties
+            # resolve identically to theirs (toward the lowest id).
+            g = io.to_host(crit.objective(rel_j, cstate, l))
+            g[mask] = _NEG_INF
+            k = int(np.argmax(g))
+            selected[l], gains[l] = k, g[k]
+            mask[k] = True
+            if l + 1 >= num_select or not crit.needs_redundancy:
+                continue
+            if k in pending:
+                red = pending.pop(k)  # speculation hit: zero I/O
+            elif q > 1:
                 # One sweep scores the needed column plus the top
                 # q-1 remaining candidates by the CURRENT objective —
                 # the same lazy-greedy bet that objectives shift
-                # slowly between folds.  Short batches pad by
-                # repeating the last column so the accumulate keeps
-                # one compiled shape per q.
+                # slowly between folds.
                 cols = [k]
                 for j in np.argsort(-g, kind="stable"):
                     if len(cols) == q:
@@ -518,21 +596,22 @@ def _greedy_select(run_pass, crit: Criterion, n: int, num_select: int, q: int):
                     if mask[j] or j in pending or g[j] == _NEG_INF:
                         continue
                     cols.append(j)
-                padded = cols + [cols[-1]] * (q - len(cols))
-                reds = run_pass(padded, batch=q)
-                for i, c in enumerate(cols):
-                    pending[c] = (
-                        {k2: v[i] for k2, v in reds.items()}
-                        if isinstance(reds, dict)
-                        else reds[i]
-                    )
-                red = pending.pop(k)
-        terms = (
-            {k2: jnp.asarray(v) for k2, v in red.items()}
-            if isinstance(red, dict)
-            else jnp.asarray(red)
-        )
-        cstate = crit.update(cstate, terms, l)
+        if red is not None:
+            continue
+        if q == 1:
+            red = run_pass(k)
+            continue
+        # Short batches pad by repeating the last column so the
+        # accumulate keeps one compiled shape per q.
+        padded = cols + [cols[-1]] * (q - len(cols))
+        reds = run_pass(padded, batch=q)
+        for i, c in enumerate(cols):
+            pending[c] = (
+                {k2: v[i] for k2, v in reds.items()}
+                if isinstance(reds, dict)
+                else reds[i]
+            )
+        red = pending.pop(k)
     return rel, selected, gains
 
 
@@ -696,20 +775,11 @@ def mrmr_streaming(
     # the codes — streams the source itself.
     block_src = binned.base if binned is not None else source
     io = _PassIO()
-    reader: CrossPassReader | None = None
-    if readahead > 0:
-        # Upper bound on passes; batching/speculation only lowers it, and
-        # close() stops the reader thread wherever the fit actually ends.
-        max_passes = num_select if crit.needs_redundancy else 1
-        reader = CrossPassReader(
-            lambda: block_src.iter_blocks(placer.block_obs),
-            depth=readahead,
-            max_passes=max_passes,
-        )
-        next_raw = reader.next_pass
+    next_raw, reader = _pass_reader(
+        block_src, placer.block_obs, io, readahead, num_select, crit
+    )
+    if reader is not None:
         prefetch = 0  # the reader thread is the producer; stage at consume
-    else:
-        next_raw = lambda: block_src.iter_blocks(placer.block_obs)
 
     def run_pass(target_cols, batch=None):
         return _score_pass(
@@ -719,7 +789,9 @@ def mrmr_streaming(
         )
 
     try:
-        rel, selected, gains = _greedy_select(run_pass, crit, n, num_select, q)
+        rel, selected, gains = _greedy_select(
+            run_pass, crit, n, num_select, q, io
+        )
     finally:
         if reader is not None:
             reader.close()
@@ -892,18 +964,11 @@ def _mrmr_streaming_multihost(
                     close()
 
     io = _PassIO()
-    reader: CrossPassReader | None = None
-    if readahead > 0:
-        max_passes = num_select if crit.needs_redundancy else 1
-        reader = CrossPassReader(
-            lambda: stream_src.iter_blocks(eff_bo),
-            depth=readahead,
-            max_passes=max_passes,
-        )
-        next_raw = reader.next_pass
+    next_raw, reader = _pass_reader(
+        stream_src, eff_bo, io, readahead, num_select, crit
+    )
+    if reader is not None:
         prefetch = 0
-    else:
-        next_raw = lambda: stream_src.iter_blocks(eff_bo)
 
     def run_pass(target_cols, batch=None):
         cond = needs_cond and target_cols is not None
@@ -946,7 +1011,9 @@ def _mrmr_streaming_multihost(
         return coll.assemble(res) if spec.partitions_cols else res
 
     try:
-        rel, selected, gains = _greedy_select(run_pass, crit, n, num_select, q)
+        rel, selected, gains = _greedy_select(
+            run_pass, crit, n, num_select, q, io
+        )
     finally:
         if reader is not None:
             reader.close()

@@ -23,7 +23,7 @@ business.  Padded feature columns produce junk statistics rows that the
 engine slices off after ``finalize``.
 
 ``PrefetchPlacer`` is the double-buffered face of the same placement: a
-bounded host thread reads and pads block ``i+1`` while the consumer
+bounded host thread reads and stages block ``i+1`` while the consumer
 places (async ``device_put``) and the device accumulates block ``i``, so
 streaming throughput approaches the device-bound in-memory rate instead
 of serialising source I/O with placement.
@@ -45,6 +45,7 @@ and statistics leaves ``(q, N, ...)`` — ``stage``/``place`` and
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import queue
 import threading
 
@@ -54,6 +55,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.dist.sharding import axes_tuple, mesh_extent
+from repro.runtime import tracing
 
 # End-of-stream sentinel for the prefetch queue.
 _DONE = object()
@@ -254,20 +256,26 @@ class BlockPlacer:
         valid = np.arange(self.block_obs) < b
         return X_block, target, valid
 
-    def place(self, staged):
+    def place(self, staged, **ids):
         """Device half: land a staged (X, target, valid) triple per the
-        mesh plan.  ``device_put`` is async — it enqueues and returns.
-        A 2-D ``(q, B)`` batched target shards its observation axis like
-        the 1-D case, with the candidate axis replicated."""
+        mesh plan, in a ``mrmr.place`` span with ``ids`` as its arguments.
+        ``device_put`` is async — it enqueues and returns.  A 2-D
+        ``(q, B)`` batched target shards its observation axis like the 1-D
+        case, with the candidate axis replicated."""
         X_block, target, valid = staged
-        if self._shard_mat is not None:
-            tgt_sh = self._shard_vec if target.ndim == 1 else self._shard_tgt2
+        with tracing.span(tracing.PLACE, **ids):
+            if self._shard_mat is not None:
+                tgt_sh = (
+                    self._shard_vec if target.ndim == 1 else self._shard_tgt2
+                )
+                return (
+                    jax.device_put(X_block, self._shard_mat),
+                    jax.device_put(target, tgt_sh),
+                    jax.device_put(valid, self._shard_vec),
+                )
             return (
-                jax.device_put(X_block, self._shard_mat),
-                jax.device_put(target, tgt_sh),
-                jax.device_put(valid, self._shard_vec),
+                jnp.asarray(X_block), jnp.asarray(target), jnp.asarray(valid)
             )
-        return jnp.asarray(X_block), jnp.asarray(target), jnp.asarray(valid)
 
     def __call__(self, X_block: np.ndarray, target: np.ndarray):
         """(B, N), (B,) host block -> placed (X, target, valid), B' fixed."""
@@ -276,14 +284,14 @@ class BlockPlacer:
 
 @dataclasses.dataclass
 class PrefetchPlacer:
-    """Double-buffered placement: a host thread runs the wrapped placer's
-    *staging* half (source read + pad — pure numpy) up to ``depth`` blocks
-    ahead, while the consumer thread runs the *placement* half
-    (``device_put``, async) and the device accumulates the previous block.
-    The worker never touches jax, so it cannot contend with the XLA
-    runtime's own thread pool.  Exceptions raised while reading or staging
-    re-raise in the consumer, and abandoning the iterator stops the
-    thread.
+    """Double-buffered placement: a host thread runs an iterator of
+    *staged* blocks (source read + :meth:`BlockPlacer.stage` — pure numpy)
+    up to ``depth`` blocks ahead, while the consumer thread runs the wrapped
+    placer's *placement* half (``device_put``, async) and the device
+    accumulates the previous block.  The worker never touches jax, so it
+    cannot contend with the XLA runtime's own thread pool.  Exceptions
+    raised while reading or staging re-raise in the consumer, and
+    abandoning the iterator stops the thread.
     """
 
     placer: BlockPlacer
@@ -293,8 +301,11 @@ class PrefetchPlacer:
         if self.depth < 1:
             raise ValueError(f"prefetch depth must be >= 1, got {self.depth}")
 
-    def stream(self, host_blocks):
-        """``(X_block, target)`` host iterator -> placed-tuple iterator."""
+    def stream(self, staged_blocks, **ids):
+        """Staged ``(X, target, valid)`` host iterator -> placed-tuple
+        iterator.  The consumer's waits on the worker are ``mrmr.feed_wait``
+        spans; they and the placements carry ``ids`` and the block's
+        index."""
         q: queue.Queue = queue.Queue(maxsize=self.depth)
         stop = threading.Event()
 
@@ -303,10 +314,10 @@ class PrefetchPlacer:
             # On early consumer exit the finally-block below sets ``stop``
             # and drains the queue until this thread observes it and dies.
             try:
-                for X_block, target in host_blocks:
+                for staged in staged_blocks:
                     if stop.is_set():
                         return
-                    q.put((self.placer.stage(X_block, target), None))
+                    q.put((staged, None))
                 q.put((_DONE, None))
             except BaseException as exc:  # re-raised by the consumer
                 q.put((None, exc))
@@ -316,13 +327,14 @@ class PrefetchPlacer:
         )
         worker.start()
         try:
-            while True:
-                staged, exc = q.get()
+            for block in itertools.count():
+                with tracing.span(tracing.FEED_WAIT, block=block, **ids):
+                    staged, exc = q.get()
                 if exc is not None:
                     raise exc
                 if staged is _DONE:
                     return
-                yield self.placer.place(staged)
+                yield self.placer.place(staged, block=block, **ids)
         finally:
             stop.set()
             while worker.is_alive():
@@ -383,10 +395,13 @@ class CrossPassReader:
         except BaseException as exc:  # re-raised by the consumer
             self._q.put((None, exc))
 
-    def next_pass(self):
-        """Iterator over the next pass's raw ``(X, y)`` host blocks."""
-        while True:
-            item, exc = self._q.get()
+    def next_pass(self, **ids):
+        """Iterator over the next pass's raw ``(X, y)`` host blocks.  Its
+        waits on the reader are ``mrmr.feed_wait`` spans with ``ids`` and
+        the block's index as their arguments."""
+        for block in itertools.count():
+            with tracing.span(tracing.FEED_WAIT, block=block, **ids):
+                item, exc = self._q.get()
             if exc is not None:
                 raise exc
             if item is _PASS_END:

@@ -78,6 +78,7 @@ def bin_codes_pallas(
         out_specs=pl.BlockSpec((tile_b, tile_n), lambda b, n: (b, n)),
         out_shape=jax.ShapeDtypeStruct((bp, np_), jnp.int32),
         interpret=interpret,
+        name="bin_codes",
     )(Xf, ef)
 
     return out[:B, :N]

@@ -19,8 +19,7 @@ the production mesh, and record
   (``repro.analysis.hlo_analysis``), scan trip counts unrolled.
 
 Results are cached as JSON under ``results/dryrun/<mesh>/<arch>__<shape>.json``
-so the matrix re-runs incrementally; EXPERIMENTS.md tables are generated
-from these files by ``benchmarks/report.py``.
+so the matrix re-runs incrementally.
 
 Usage::
 

@@ -585,7 +585,8 @@ class TestPrefetch:
             (np.full((4, 3), i, np.int8), np.full((4,), i, np.int8))
             for i in range(5)
         ]
-        out = list(PrefetchPlacer(placer, depth=2).stream(iter(blocks)))
+        staged = (placer.stage(X, t) for X, t in blocks)
+        out = list(PrefetchPlacer(placer, depth=2).stream(staged))
         assert len(out) == 5
         for i, (X, t, valid) in enumerate(out):
             assert int(np.asarray(X)[0, 0]) == i
@@ -600,7 +601,7 @@ class TestPrefetch:
         def blocks():
             for i in range(1000):
                 produced.append(i)
-                yield np.zeros((2, 1), np.int8), np.zeros(2, np.int8)
+                yield placer.stage(np.zeros((2, 1), np.int8), np.zeros(2, np.int8))
 
         stream = PrefetchPlacer(placer, depth=1).stream(blocks())
         next(stream)
