@@ -61,6 +61,19 @@ under every combination:
   ``prefetch`` thread: the reader is the producer and staging runs at
   consume time.
 
+Where the device can hold the whole dataset, the ``L``-pass tax falls to
+one pass without a knob: a single-host fit of more than one pass keeps
+the placed blocks of its relevance pass on the device
+(:class:`~repro.dist.streaming.ResidentBlocks`) when they fit
+:func:`~repro.dist.streaming.resident_budget`, a share of the free
+device memory the backend reports less what running fits have been
+promised, and counts every later pass from them with its target cut on
+the device — the same accumulate programs on the
+same blocks in the same order, so selections stay bitwise.  The blocks
+are freed when the fit returns or raises.  Fits above the budget,
+multi-host fits, the fused binned path and backends that report no
+memory (the CPU) stream every pass.
+
 Both of the paper's §III regimes stream:
 
 * **tall** — blocks shard over ``obs_axes`` (the paper's conventional
@@ -90,7 +103,9 @@ math above is asserted by tests and benchmarks, not eyeballed.  It also
 counts the host round trips: ``host_syncs`` (device-to-host copies, one
 per finalize term and one per pick's objective) and ``h2d_bytes`` (host
 arrays placed on the device: every block triple, plus the vectors the
-greedy loop folds).  Each layer boundary is a ``mrmr.*`` profiler span
+greedy loop folds, and the column ids a resident pass cuts its targets
+by), and ``resident_passes``, the passes counted from device-resident
+blocks.  Each layer boundary is a ``mrmr.*`` profiler span
 (:mod:`repro.runtime.tracing`).
 """
 
@@ -118,6 +133,7 @@ from repro.dist.streaming import (
     BlockPlacer,
     CrossPassReader,
     PrefetchPlacer,
+    ResidentBlocks,
     resolve_prefetch,
 )
 from repro.runtime import tracing
@@ -375,6 +391,7 @@ class _PassIO:
         self.state_bytes = 0
         self.host_syncs = 0
         self.h2d_bytes = 0
+        self.resident_passes = 0
 
     def count(self, raw_blocks):
         for X_blk, y_blk in raw_blocks:
@@ -387,7 +404,9 @@ class _PassIO:
         self.state_bytes = max(self.state_bytes, size)
 
     def note_placed(self, arrays):
+        """Count the bytes of placed ``arrays``; returns them."""
         self.h2d_bytes += sum(a.nbytes for a in arrays)
+        return arrays
 
     def to_device(self, tree):
         """Host arrays -> device arrays, their bytes counted."""
@@ -407,6 +426,7 @@ class _PassIO:
             state_bytes=self.state_bytes,
             host_syncs=self.host_syncs,
             h2d_bytes=self.h2d_bytes,
+            resident_passes=self.resident_passes,
         )
 
 
@@ -424,6 +444,7 @@ def _score_pass(
     conditional: bool = False,
     merge_state=None,
     keep: int | None = None,
+    resident: ResidentBlocks | None = None,
 ):
     """One full map-reduce pass over ``raw_pass`` (an ``(X, y)`` raw host
     block iterator): ``(N,)`` scores of every feature against the class
@@ -440,7 +461,12 @@ def _score_pass(
     exactly as if one process had counted every block.  ``keep``
     overrides how many leading feature rows survive the padding slice
     (default: the source's full width; a column-sharded host keeps only
-    its own columns, dropping appended target columns too)."""
+    its own columns, dropping appended target columns too).
+
+    ``resident`` keeps the fit's blocks on the device: the first pass
+    (the relevance pass) places ``raw_pass`` and keeps every placed block;
+    once it has, a pass ignores ``raw_pass`` and counts from the kept
+    blocks, its target cut on the device."""
     ids = {"fit": io.fit, "pass": io.passes}
     io.passes += 1
     binner = binned.binner if binned is not None else None
@@ -450,7 +476,11 @@ def _score_pass(
         if target_cols is None
         else ("feature_cond" if cond else "feature")
     )
-    with tracing.span(tracing.PASS, kind=kind, batch=batch or 1, **ids):
+    from_resident = resident is not None and resident.complete
+    with tracing.span(
+        tracing.PASS, kind=kind, batch=batch or 1,
+        resident=int(from_resident), **ids,
+    ):
         if batch is None:
             state = score.init_state(placer.padded_features, kind)
         else:
@@ -475,17 +505,24 @@ def _score_pass(
                     staged = placer.stage(X_blk, target)
                 yield staged
 
-        if prefetch > 0:
-            placed = PrefetchPlacer(placer, depth=prefetch).stream(
-                staged_blocks(), **ids
-            )
+        if from_resident:
+            io.resident_passes += 1
+            (cols,) = io.note_placed([placer.place_ids(target_cols)])
+            placed = resident.triples(cols, cond_classes, **ids)
         else:
-            placed = (
-                placer.place(staged, block=block, **ids)
-                for block, staged in enumerate(staged_blocks())
-            )
+            if prefetch > 0:
+                placed = PrefetchPlacer(placer, depth=prefetch).stream(
+                    staged_blocks(), **ids
+                )
+            else:
+                placed = (
+                    placer.place(staged, block=block, **ids)
+                    for block, staged in enumerate(staged_blocks())
+                )
+            placed = map(io.note_placed, placed)
+            if resident is not None and target_cols is None:
+                placed = resident.keep(placed)
         for block, triple in enumerate(placed):
-            io.note_placed(triple)
             with tracing.span(tracing.ACCUMULATE, block=block, **ids):
                 state = acc_fn(state, *triple)
         if merge_state is not None:
@@ -513,14 +550,13 @@ def _pass_reader(
     block_obs: int,
     io: _PassIO,
     readahead: int,
-    num_select: int,
-    crit: Criterion,
+    max_passes: int,
 ):
     """-> ``(next_raw, reader)``: ``next_raw()`` gives the next pass's raw
     block iterator, each read in a ``mrmr.read`` span.  With ``readahead``
     the reads run on a :class:`~repro.dist.streaming.CrossPassReader`
-    thread (returned, for the caller to close), else where the pass
-    iterates."""
+    thread (returned, for the caller to close) that reads at most
+    ``max_passes`` passes, else where the pass iterates."""
     pass_ids = itertools.count()
 
     def read_pass():
@@ -531,16 +567,39 @@ def _pass_reader(
 
     if readahead <= 0:
         return read_pass, None
-    # Upper bound on passes; batching/speculation only lowers it, and
-    # close() stops the reader thread wherever the fit actually ends.
-    reader = CrossPassReader(
-        read_pass,
-        depth=readahead,
-        max_passes=num_select if crit.needs_redundancy else 1,
-    )
+    reader = CrossPassReader(read_pass, depth=readahead, max_passes=max_passes)
     return (
         lambda: reader.next_pass(fit=io.fit, **{"pass": io.passes})
     ), reader
+
+
+def _max_passes(
+    crit: Criterion, num_select: int, resident: ResidentBlocks | None = None
+) -> int:
+    """Upper bound on the passes a fit reads from its source; batching and
+    speculation only lower it, and the cross-pass reader stops wherever the
+    fit actually ends.  A resident fit reads its first pass alone."""
+    if resident is not None or not crit.needs_redundancy:
+        return 1
+    return num_select
+
+
+def _resident_blocks(
+    placer: BlockPlacer, source: DataSource, crit: Criterion, num_select: int
+) -> ResidentBlocks | None:
+    """Keep this fit's blocks on the device when it makes more than one
+    pass and the whole placed dataset fits :func:`~repro.dist.streaming.
+    resident_budget` of every device it lands on (its bytes then stay
+    promised until the fit ends); else None, and every pass streams.  A
+    source whose feature dtype is unknown before a read is counted at 8
+    bytes a value."""
+    if num_select < 2 or not crit.needs_redundancy:
+        return None
+    dtype = source.feature_dtype
+    itemsize = 8 if dtype is None else np.dtype(dtype).itemsize
+    return ResidentBlocks.reserve(
+        placer, placer.resident_bytes(source.num_obs, itemsize)
+    )
 
 
 def _greedy_select(
@@ -662,11 +721,12 @@ def mrmr_streaming(
         memory and identical selections.
       spill_dir: directory for the encoded-block spill cache — pass 1
         writes parsed/encoded blocks, passes 2..L replay them memmapped
-        (zero parse, zero re-encode).  ``spill_budget_bytes`` bounds the
-        directory LRU-wise.
+        (zero parse, zero re-encode); a resident fit writes them and
+        replays nothing.  ``spill_budget_bytes`` bounds the directory
+        LRU-wise.
       readahead: raw blocks the cross-pass reader streams ahead of the
-        consumer, across pass boundaries (0 = off).  Supersedes
-        ``prefetch`` when positive.
+        consumer, across pass boundaries (0 = off); a resident fit reads
+        its first pass alone.  Supersedes ``prefetch`` when positive.
       shards: a :class:`~repro.dist.multihost.HostShardSpec` placing this
         process on the cross-host grid — the fit then reads ONLY this
         host's block/column ranges and merges per-pass statistics with
@@ -774,18 +834,25 @@ def mrmr_streaming(
     # including a spill-cached binned source, whose cache already holds
     # the codes — streams the source itself.
     block_src = binned.base if binned is not None else source
+    resident = (
+        None if binned is not None
+        else _resident_blocks(placer, source, crit, num_select)
+    )
     io = _PassIO()
     next_raw, reader = _pass_reader(
-        block_src, placer.block_obs, io, readahead, num_select, crit
+        block_src, placer.block_obs, io, readahead,
+        _max_passes(crit, num_select, resident),
     )
     if reader is not None:
         prefetch = 0  # the reader thread is the producer; stage at consume
 
     def run_pass(target_cols, batch=None):
+        raw = None if resident is not None and resident.complete else next_raw()
         return _score_pass(
-            next_raw(), source, score, acc_fn if batch is None else acc_fn_q,
+            raw, source, score, acc_fn if batch is None else acc_fn_q,
             placer, target_cols, prefetch, io, binned, batch,
             conditional=needs_cond and target_cols is not None,
+            resident=resident,
         )
 
     try:
@@ -795,6 +862,8 @@ def mrmr_streaming(
     finally:
         if reader is not None:
             reader.close()
+        if resident is not None:
+            resident.delete()
     io_report = io.as_dict()
     if spill is not None:
         io_report["cache"] = dict(spill.counters)
@@ -965,7 +1034,7 @@ def _mrmr_streaming_multihost(
 
     io = _PassIO()
     next_raw, reader = _pass_reader(
-        stream_src, eff_bo, io, readahead, num_select, crit
+        stream_src, eff_bo, io, readahead, _max_passes(crit, num_select)
     )
     if reader is not None:
         prefetch = 0

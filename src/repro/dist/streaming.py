@@ -40,11 +40,19 @@ Batched redundancy passes (``batch_candidates > 1``) reuse all of this
 unchanged except for a leading candidate axis: targets become ``(q, B)``
 and statistics leaves ``(q, N, ...)`` — ``stage``/``place`` and
 ``state_shardings`` recognise both layouts.
+
+``ResidentBlocks`` keeps the placed blocks of a fit's first pass on the
+device when the whole placed dataset fits ``resident_budget`` (a share of
+the free device memory, where the backend reports it, less what fits
+already running have been promised): every later pass of that fit counts
+from them, its target cut on the device (``BlockPlacer.cut_target``), and
+reads and places nothing.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 import queue
 import threading
@@ -62,6 +70,43 @@ _DONE = object()
 
 # End-of-pass sentinel for the cross-pass read-ahead queue.
 _PASS_END = object()
+
+# Share of the smallest free device memory that one fit's resident blocks
+# may take.  The rest is left to the statistics state, each pass's widened
+# block and whatever else shares the devices (concurrent fits included).
+RESIDENT_FRACTION = 0.5
+
+# Bytes a row of a resident block's target is counted at: the widest a
+# class label takes (int64/float64), so a source's label dtype need not be
+# known before the first read.
+_RESIDENT_TARGET_BYTES = 8
+
+# Bytes per device promised to the resident blocks of live fits, from each
+# fit's decision (ResidentBlocks.reserve) to its delete().  A fit places its
+# blocks over the whole of its first pass, so a fit deciding meanwhile must
+# not count that memory as free.
+_RESERVE_LOCK = threading.Lock()
+_RESERVED: dict = {}
+
+
+def resident_budget(devices) -> int | None:
+    """Bytes per device that one fit may keep resident:
+    :data:`RESIDENT_FRACTION` of the smallest ``bytes_limit − bytes_in_use``
+    less the bytes promised to live fits' resident blocks, over
+    ``devices``, measured now.  A promise still counts once its blocks are
+    placed (and so in use), which errs toward streaming.  ``None`` where a
+    device reports no memory statistics (the CPU backend): such a fit
+    streams every pass."""
+    free = []
+    for d in devices:
+        stats = d.memory_stats()
+        if not stats or "bytes_limit" not in stats:
+            return None
+        free.append(
+            stats["bytes_limit"] - stats.get("bytes_in_use", 0)
+            - _RESERVED.get(d, 0)
+        )
+    return max(0, int(RESIDENT_FRACTION * min(free)))
 
 
 def resolve_prefetch(prefetch, backend: str | None = None) -> int:
@@ -280,6 +325,121 @@ class BlockPlacer:
     def __call__(self, X_block: np.ndarray, target: np.ndarray):
         """(B, N), (B,) host block -> placed (X, target, valid), B' fixed."""
         return self.place(self.stage(X_block, target))
+
+    # -- device-resident blocks -----------------------------------------
+
+    @property
+    def devices(self) -> list:
+        """The devices the blocks land on."""
+        if self.mesh is not None:
+            return list(self.mesh.devices.flat)
+        return jax.devices()[:1]
+
+    def resident_bytes(self, num_obs: int, itemsize: int) -> int:
+        """Per-device bytes of ``num_obs`` rows placed as this placer's
+        blocks: blocks × rows per shard × (padded features per shard ×
+        ``itemsize`` + target + validity byte)."""
+        blocks = -(-int(num_obs) // self.block_obs)
+        rows = self.block_obs // mesh_extent(self.mesh, self.obs_axes)
+        cols = self.padded_features // mesh_extent(self.mesh, self.feat_axes)
+        return blocks * rows * (cols * itemsize + _RESIDENT_TARGET_BYTES + 1)
+
+    def place_ids(self, ids):
+        """Column ids as int32 on the placer's devices, replicated over
+        the mesh, for :meth:`cut_target`."""
+        ids = np.asarray(ids, np.int32)
+        if self.mesh is None:  # uncommitted, as place() leaves a block
+            return jnp.asarray(ids)
+        return jax.device_put(ids, NamedSharding(self.mesh, P()))
+
+    def cut_target(self, X, y, cols, cond_classes: int | None = None):
+        """Device twin of the host's target extraction, from a placed
+        ``(X, y)``: column ``cols`` of X (a scalar -> ``(B,)``) or a
+        ``(q, B)`` stack of columns (``(q,)`` ids), fused with the class
+        as ``col * cond_classes + y`` in int32 when ``cond_classes`` is
+        given.  Padded rows stay zero, and the target lands with the
+        sharding :meth:`place` gives a target."""
+        sharding = None
+        if self.mesh is not None:
+            sharding = self._shard_vec if cols.ndim == 0 else self._shard_tgt2
+        return _cut_target(X, y, cols, cond_classes, sharding)
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def _cut_target(X, y, cols, cond_classes, sharding):
+    # Integer data movement, so exact under any partitioning XLA picks.
+    t = jnp.take(X, cols, axis=1, mode="clip").T  # (B, q) -> (q, B)
+    if cond_classes is not None:
+        t = t.astype(jnp.int32) * cond_classes + y.astype(jnp.int32)
+    if sharding is not None:
+        t = jax.lax.with_sharding_constraint(t, sharding)
+    return t
+
+
+class ResidentBlocks:
+    """The placed ``(X, y, valid)`` triples of one fit's first pass, kept
+    on the device for the rest of that fit.
+
+    :meth:`reserve` decides whether a fit keeps its blocks and promises
+    their bytes; the first pass runs its placed blocks through
+    :meth:`keep`; once it has ended (:attr:`complete`), each later pass
+    counts from :meth:`triples` in the same block order, its target cut on
+    the device, and reads and places nothing.  The owner calls
+    :meth:`delete` when the fit returns or raises: nothing outlives the
+    fit, and the promise is withdrawn.
+    """
+
+    def __init__(self, placer: BlockPlacer, nbytes: int = 0):
+        self.placer = placer
+        self.blocks: list = []
+        self.complete = False
+        self._promised = {d: nbytes for d in placer.devices} if nbytes else {}
+
+    @classmethod
+    def reserve(cls, placer: BlockPlacer, nbytes: int) -> ResidentBlocks | None:
+        """Resident blocks for a fit needing ``nbytes`` a device, when they
+        fit :func:`resident_budget` of every device of ``placer``; the
+        bytes stay promised until :meth:`delete`.  None where they do not
+        fit, or the backend reports no memory: that fit streams."""
+        with _RESERVE_LOCK:
+            budget = resident_budget(placer.devices)
+            if budget is None or nbytes > budget:
+                return None
+            kept = cls(placer, nbytes)
+            for d, n in kept._promised.items():
+                _RESERVED[d] = _RESERVED.get(d, 0) + n
+        return kept
+
+    def keep(self, placed):
+        """Pass-through of the first pass's placed triples, keeping each."""
+        for triple in placed:
+            self.blocks.append(triple)
+            yield triple
+        self.complete = True
+
+    def triples(self, cols, cond_classes: int | None = None, **ids):
+        """Each kept block as ``(X, target, valid)`` for a pass whose
+        target is column ``cols`` (a scalar) or a stack of columns (a
+        vector), placed by :meth:`BlockPlacer.place_ids`; each cut is
+        dispatched in a ``mrmr.cut`` span with ``ids`` and the block's
+        index."""
+        for block, (X, y, valid) in enumerate(self.blocks):
+            with tracing.span(tracing.CUT, block=block, **ids):
+                target = self.placer.cut_target(X, y, cols, cond_classes)
+            yield X, target, valid
+
+    def delete(self) -> None:
+        """Free the kept blocks' device memory and withdraw the promise."""
+        for triple in self.blocks:
+            for a in triple:
+                a.delete()
+        self.blocks = []
+        with _RESERVE_LOCK:
+            for d, n in self._promised.items():
+                left = _RESERVED.pop(d) - n
+                if left:
+                    _RESERVED[d] = left
+        self._promised = {}
 
 
 @dataclasses.dataclass

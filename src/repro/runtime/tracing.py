@@ -18,11 +18,16 @@ The spans, outermost first:
 * ``mrmr.fit``: one front-door fit of a source, planning to result;
 * ``mrmr.plan``: the front door before the engine (score and its stats
   scan, plan, mesh);
-* ``mrmr.pass``: one scoring pass (args ``kind`` and ``batch``);
+* ``mrmr.pass``: one scoring pass (args ``kind``, ``batch`` and
+  ``resident``, 1 where the pass counts from device-resident blocks and
+  reads nothing);
 * ``mrmr.read``: one raw block read from the source, on whichever thread
   reads;
 * ``mrmr.stage``: target extraction, pad and mask of one block, the host
   half of placement;
+* ``mrmr.cut``: in a pass counted from device-resident blocks, the
+  dispatch of one block's target cut on the device, in place of the
+  stage and the transfers;
 * ``mrmr.feed_wait``: the consumer blocked on the staging or read-ahead
   thread;
 * ``mrmr.place``: the host-to-device transfers of one staged block;
@@ -49,12 +54,13 @@ READ = "mrmr.read"
 STAGE = "mrmr.stage"
 FEED_WAIT = "mrmr.feed_wait"
 PLACE = "mrmr.place"
+CUT = "mrmr.cut"
 ACCUMULATE = "mrmr.accumulate"
 FINALIZE = "mrmr.finalize"
 PICK = "mrmr.pick"
 SPANS = (
-    FIT, PLAN, PASS, READ, STAGE, FEED_WAIT, PLACE, ACCUMULATE, FINALIZE,
-    PICK,
+    FIT, PLAN, PASS, READ, STAGE, FEED_WAIT, PLACE, CUT, ACCUMULATE,
+    FINALIZE, PICK,
 )
 
 _FIT_IDS = itertools.count(1)

@@ -352,8 +352,11 @@ class SelectionService:
 
     Args:
       workers: worker threads draining the queue (each runs one engine fit
-        at a time; streamed fits bound their own device memory, so worker
-        count × ``block_obs`` is the service's peak-memory envelope).
+        at a time).  A streamed fit holds a few ``block_obs`` blocks on the
+        device, or its whole placed dataset where that fits half of the
+        device memory left free and unpromised to the fits already
+        running (:func:`repro.dist.streaming.resident_budget`); so
+        concurrent resident fits together take at most the free memory.
       queue_capacity: bound on QUEUED jobs; beyond it ``submit`` raises
         :class:`Backpressure` (coalesced and cache-hit submissions never
         occupy a slot).

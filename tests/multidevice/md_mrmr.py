@@ -158,6 +158,70 @@ def main() -> None:
         print(f"MRMRSelector auto ({shape_hint} -> "
               f"{sel.plan_.encoding}, mesh={sel.plan_.mesh_shape}): OK")
 
+    # --- streamed fits counting from device-resident blocks ---------------
+    # The CPU reports no device memory, so the budget is set by hand: the
+    # resident fit must be bitwise the streamed fit, on a 4-device
+    # obs-sharded mesh (last block short) and on a feature-sharded wide
+    # mesh (203 columns padded to 208).
+    import repro.dist.streaming as dist
+    from repro.data.sources import ArraySource
+    from repro.dist.streaming import BlockPlacer, ResidentBlocks
+
+    mesh4 = make_mesh((4,), ("data",), devices=jax.devices()[:4])
+    Xw = rng.integers(0, 3, (60, 203)).astype(np.int8)
+    yw = ((Xw[:, 0] + Xw[:, 7]) % 2).astype(np.int8)
+    budget_fn = dist.resident_budget
+    for name, msh, Xs, ys, bo in [
+        ("obs 4-way", mesh4, X, y, 100),
+        ("wide feature 8-way", mesh_m, Xw, yw, 16),
+    ]:
+        for crit in ("mid", "jmi"):
+            for q in (1, 3):
+                fits = []
+                for budget in (None, 1 << 40):
+                    dist.resident_budget = lambda devices, b=budget: b
+                    fits.append(MRMRSelector(
+                        num_select=5, score=score, criterion=crit, mesh=msh,
+                        block_obs=bo, batch_candidates=q,
+                    ).fit(ArraySource(Xs, ys)))
+                streamed, resident = fits
+                np.testing.assert_array_equal(
+                    resident.selected_, streamed.selected_
+                )
+                for got, want in [(resident.gains_, streamed.gains_),
+                                  (resident.scores_, streamed.scores_)]:
+                    np.testing.assert_array_equal(
+                        got.view(np.int32), want.view(np.int32)
+                    )
+                io = resident.result_.io
+                assert io["resident_passes"] == io["passes"] - 1 > 0, io
+                assert streamed.result_.io["resident_passes"] == 0
+        print(f"resident fit {name}: bitwise the streamed fit: OK")
+    dist.resident_budget = budget_fn
+
+    # the device-cut target carries the placer's target sharding
+    for msh, obs, feat in [(mesh4, ("data",), ()), (mesh_m, (), ("model",)),
+                           (mesh_g, ("data",), ("model",))]:
+        placer = BlockPlacer(16, msh, obs, feat, num_features=203)
+        Xh, yh = Xw[:13], yw[:13]  # 13 rows pad to 16
+        triple = placer(Xh, yh)
+        kept = ResidentBlocks(placer)
+        list(kept.keep([triple]))
+        for cols, cond in [(7, None), (202, 2), ([3, 150, 202], None),
+                           ([3, 150, 202], 2)]:
+            ((_, cut, _),) = kept.triples(placer.place_ids(cols), cond)
+            host = Xh[:, cols].T
+            if cond is not None:
+                host = (host.astype(np.int64) * cond + yh).astype(np.int32)
+            placed = placer.place(placer.stage(Xh, host))[1]
+            assert cut.sharding.is_equivalent_to(placed.sharding, cut.ndim), (
+                cut.sharding, placed.sharding,
+            )
+            assert cut.dtype == placed.dtype
+            np.testing.assert_array_equal(np.asarray(cut), np.asarray(placed))
+        kept.delete()
+    print("resident target cut shardings: OK")
+
     print("ALL-MD-MRMR-OK")
 
 

@@ -831,3 +831,254 @@ class TestBinnedStreaming:
             codes.astype(np.float32), labels
         )
         np.testing.assert_array_equal(got.selected_, want.selected_)
+
+
+class TestResidentBlocks:
+    """A fit whose placed dataset fits the device budget keeps its blocks
+    on the device after the relevance pass and counts every later pass
+    from them.  The CPU backend reports no memory, so these tests set the
+    budget by hand."""
+
+    ROWS, COLS, BLOCK, SELECT = 1000, 13, 256, 6  # last block 232 rows
+
+    @pytest.fixture(scope="class")
+    def data(self):
+        rng = np.random.default_rng(11)
+        X = rng.integers(0, 3, size=(self.ROWS, self.COLS)).astype(np.int8)
+        y = ((X[:, 0] + X[:, 1]) % 2).astype(np.int8)
+        flip = rng.random(self.ROWS) < 0.1
+        y[flip] = 1 - y[flip]
+        return X, y
+
+    @staticmethod
+    def budget(monkeypatch, nbytes):
+        import repro.dist.streaming as dist
+
+        monkeypatch.setattr(dist, "resident_budget", lambda devices: nbytes)
+
+    def fit(self, data, source=None, **kw):
+        kw = dict(num_select=self.SELECT, block_obs=self.BLOCK) | kw
+        return MRMRSelector(**kw).fit(source or ArraySource(*data))
+
+    @staticmethod
+    def assert_same(a, b):
+        np.testing.assert_array_equal(a.selected_, b.selected_)
+        np.testing.assert_array_equal(
+            a.gains_.view(np.int32), b.gains_.view(np.int32)
+        )
+        np.testing.assert_array_equal(
+            a.scores_.view(np.int32), b.scores_.view(np.int32)
+        )
+
+    def test_budget_is_a_share_of_the_least_free_memory(self, monkeypatch):
+        import repro.dist.streaming as dist
+        from repro.dist.streaming import RESIDENT_FRACTION, resident_budget
+
+        class Device:
+            def __init__(self, stats):
+                self.stats = stats
+
+            def memory_stats(self):
+                return self.stats
+
+        devices = [
+            Device(dict(bytes_limit=1000, bytes_in_use=200)),
+            Device(dict(bytes_limit=1000, bytes_in_use=600)),
+        ]
+        assert resident_budget(devices) == int(RESIDENT_FRACTION * 400)
+        # bytes promised to a live fit's resident blocks are not free
+        monkeypatch.setitem(dist._RESERVED, devices[1], 100)
+        assert resident_budget(devices) == int(RESIDENT_FRACTION * 300)
+        assert resident_budget(devices + [Device(None)]) is None
+        assert resident_budget(jax.devices()[:1]) is None  # the CPU backend
+
+    @pytest.mark.parametrize("q", [1, 4])
+    @pytest.mark.parametrize("criterion", ["mid", "miq", "jmi", "cmim"])
+    def test_bitwise_the_streamed_fit(self, data, monkeypatch, criterion, q):
+        streamed = self.fit(data, criterion=criterion, batch_candidates=q)
+        self.budget(monkeypatch, 1 << 40)
+        resident = self.fit(data, criterion=criterion, batch_candidates=q)
+        self.assert_same(resident, streamed)
+
+        io, was = resident.result_.io, streamed.result_.io
+        X, y = data
+        blocks = -(-self.ROWS // self.BLOCK)
+        assert io["passes"] == was["passes"] > 1
+        assert io["resident_passes"] == io["passes"] - 1
+        assert was["resident_passes"] == 0
+        assert io["blocks_read"] == blocks
+        assert io["bytes_read"] == X.nbytes + y.nbytes
+        # one pass of placed triples (int8 X, int8 y, bool validity),
+        # the relevance vector and each folded redundancy term, and the
+        # int32 ids of the q columns each later pass cuts
+        terms = 1 + (self.SELECT - 1) * (2 if criterion in ("jmi", "cmim") else 1)
+        vectors = 4 * self.COLS * terms
+        ids = 4 * q * io["resident_passes"]
+        assert io["h2d_bytes"] == (
+            blocks * self.BLOCK * (self.COLS + 2) + vectors + ids
+        )
+        assert io["host_syncs"] == was["host_syncs"]
+        assert io["state_bytes"] == was["state_bytes"]
+
+    def test_float_blocks_bitwise(self, monkeypatch):
+        # A continuous score cuts float32 columns on the device.
+        rng = np.random.default_rng(12)
+        X = rng.normal(size=(700, 9)).astype(np.float32)
+        y = (X[:, 0] + 0.5 * rng.normal(size=700)).astype(np.float32)
+        kw = dict(num_select=4, score=PearsonMIScore(), block_obs=256)
+        streamed = MRMRSelector(**kw).fit(ArraySource(X, y))
+        self.budget(monkeypatch, 1 << 40)
+        resident = MRMRSelector(**kw).fit(ArraySource(X, y))
+        self.assert_same(resident, streamed)
+        assert resident.result_.io["resident_passes"] == 3
+
+    @pytest.mark.parametrize(
+        "case", ["over_budget", "at_budget", "maxrel", "one_pick", "binned"]
+    )
+    def test_which_fits_stream(self, data, monkeypatch, case):
+        from repro.dist.streaming import BlockPlacer
+
+        kw, source = {}, None
+        need = BlockPlacer(self.BLOCK, num_features=self.COLS).resident_bytes(
+            self.ROWS, 1
+        )
+        budget = 1 << 40
+        if case == "over_budget":
+            budget = need - 1
+        elif case == "at_budget":
+            budget = need
+        elif case == "maxrel":
+            kw = dict(criterion="maxrel")
+        elif case == "one_pick":
+            kw = dict(num_select=1)
+        else:  # the fused binned path: float blocks encoded on the device
+            X, y = data
+            source = ArraySource(X.astype(np.float32) + 0.5, y)
+            kw = dict(bins=3)
+        streamed = self.fit(data, source, **kw)
+        self.budget(monkeypatch, budget)
+        got = self.fit(data, source, **kw)
+        self.assert_same(got, streamed)
+        io, was = got.result_.io, streamed.result_.io
+        if case == "at_budget":
+            assert io["resident_passes"] == io["passes"] - 1 > 0
+            return
+        assert io["resident_passes"] == 0
+        assert io["h2d_bytes"] == was["h2d_bytes"]
+        assert io["bytes_read"] == was["bytes_read"]
+
+    @pytest.mark.parametrize(
+        "prefetch,readahead", [(0, 0), (2, 0), (0, 2)],
+        ids=["sync", "prefetch2", "readahead2"],
+    )
+    def test_reads_the_source_once(self, data, monkeypatch, prefetch, readahead):
+        reads = []
+
+        class Counting(ArraySource):
+            def iter_blocks(self, block_obs):
+                for block in super().iter_blocks(block_obs):
+                    reads.append(block[0].shape[0])
+                    yield block
+
+        self.budget(monkeypatch, 1 << 40)
+        # a given score: the front door makes no stats scan of its own
+        got = self.fit(
+            data, Counting(*data), score=MIScore(3, 2), prefetch=prefetch,
+            readahead=readahead,
+        )
+        assert got.result_.io["resident_passes"] == self.SELECT - 1
+        # a read-ahead thread would have read into pass 2 by now
+        assert sum(reads) == self.ROWS
+        import threading
+
+        assert not any(
+            t.name in ("block-prefetch", "cross-pass-readahead")
+            and t.is_alive()
+            for t in threading.enumerate()
+        )
+
+    @pytest.mark.parametrize("ends", ["returns", "raises"])
+    def test_no_device_array_outlives_the_fit(self, data, monkeypatch, ends):
+        import gc
+
+        import repro.dist.streaming as dist
+        from repro.dist.streaming import ResidentBlocks
+
+        self.budget(monkeypatch, 1 << 40)
+        self.fit(data)  # compile outside the count
+        kept, held = [], []
+        triples = ResidentBlocks.triples
+
+        def spy(resident, *a, **kw):
+            kept.append(resident)
+            held.extend(resident.blocks)
+            if ends == "raises" and len(kept) == 3:
+                raise RuntimeError("device lost")
+            return triples(resident, *a, **kw)
+
+        monkeypatch.setattr(ResidentBlocks, "triples", spy)
+        gc.collect()
+        before = {id(a) for a in jax.live_arrays()}
+        if ends == "raises":
+            with pytest.raises(RuntimeError, match="device lost"):
+                self.fit(data)
+            result = ()
+        else:
+            res = self.fit(data).result_
+            result = {id(res.selected), id(res.gains), id(res.relevance)}
+        gc.collect()
+        left = [
+            a for a in jax.live_arrays()
+            if id(a) not in before and id(a) not in result
+        ]
+        assert kept and not left, [(a.shape, a.dtype) for a in left]
+        # freed even where a reference outlives the fit (a traceback's),
+        # and their bytes no longer promised
+        assert held and all(a.is_deleted() for t in held for a in t)
+        assert not dist._RESERVED
+
+    def test_concurrent_fits_share_the_budget(self, data, monkeypatch):
+        # A fit places its resident blocks over the whole of its first
+        # pass.  A second fit that decides meanwhile must see them as
+        # taken: with free memory for one dataset's blocks at the
+        # resident fraction but not for two, it streams.
+        import threading
+
+        import repro.dist.streaming as dist
+        from repro.dist.streaming import BlockPlacer
+
+        need = BlockPlacer(self.BLOCK, num_features=self.COLS).resident_bytes(
+            self.ROWS, 1
+        )
+        limit = 5 * need // 2  # half of it fits one; half of the rest not
+        monkeypatch.setattr(
+            type(jax.devices()[0]), "memory_stats",
+            lambda device: dict(bytes_limit=limit, bytes_in_use=0),
+        )
+        decided, go = threading.Event(), threading.Event()
+
+        class Paused(ArraySource):
+            def iter_blocks(self, block_obs):
+                decided.set()  # the first read comes after the decision
+                assert go.wait(60)
+                yield from super().iter_blocks(block_obs)
+
+        kw = dict(score=MIScore(3, 2), prefetch=0)
+        fits = {}
+        first = threading.Thread(
+            target=lambda: fits.update(first=self.fit(data, Paused(*data), **kw))
+        )
+        first.start()
+        try:
+            assert decided.wait(60)
+            second = self.fit(data, **kw)
+        finally:
+            go.set()
+            first.join(60)
+        assert fits["first"].result_.io["resident_passes"] == self.SELECT - 1
+        assert second.result_.io["resident_passes"] == 0
+        self.assert_same(second, fits["first"])
+        assert not dist._RESERVED
+        # once the first fit has ended its bytes are free again
+        again = self.fit(data, **kw)
+        assert again.result_.io["resident_passes"] == self.SELECT - 1

@@ -22,7 +22,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.scores import MIScore
 from repro.core.streaming import _cached_acc_fn
-from repro.dist.streaming import BlockPlacer
+from repro.dist.streaming import BlockPlacer, _cut_target
 from repro.kernels import ops
 from repro.kernels.binning import bin_codes_pallas
 from repro.kernels.contingency import (
@@ -158,3 +158,49 @@ def test_sharded_accumulate_compiles_for_2x2(
     assert "tpu_custom_call" in text
     if layout == "obs":
         assert "all-reduce" in text  # the per-block psum of the counts
+
+
+@pytest.mark.parametrize(
+    "layout,rows,cols,q,cond",
+    [
+        ("one", BLOCK, 1000, None, None),  # a mid redundancy target
+        ("one", BLOCK, 1000, None, 2),  # jmi's class-fused target
+        ("one", BLOCK, 1000, 4, None),  # a (q, B) batch of columns
+        ("obs", BLOCK, 1000, None, 2),
+        ("feat", 2048, 50000, 4, 2),
+    ],
+)
+def test_target_cut_compiles_for_v5e(
+    layout, rows, cols, q, cond, topo, one_chip, no_persistent_cache
+):
+    # The device cut of a resident pass's target, at the benchmark's
+    # block shapes: one chip, and blocks split by rows or by columns
+    # over four.
+    if layout == "one":
+        obs, feat, sharding = (), (), None
+        x_sh = y_sh = c_sh = one_chip
+    else:
+        axis = "data" if layout == "obs" else "model"
+        mesh = Mesh(
+            np.asarray(topo.devices).reshape(4), (axis,),
+            axis_types=(AxisType.Auto,),
+        )
+        obs, feat = ((axis,), ()) if layout == "obs" else ((), (axis,))
+        placer = BlockPlacer(rows, mesh, obs, feat, num_features=cols)
+        cols = placer.padded_features
+        sharding = placer._shard_vec if q is None else placer._shard_tgt2
+        o, f = obs[0] if obs else None, feat[0] if feat else None
+        x_sh = NamedSharding(mesh, P(o, f))
+        y_sh = NamedSharding(mesh, P(o))
+        c_sh = NamedSharding(mesh, P())
+    shapes = [
+        jax.ShapeDtypeStruct((rows, cols), jnp.int8, sharding=x_sh),
+        jax.ShapeDtypeStruct((rows,), jnp.int8, sharding=y_sh),
+        jax.ShapeDtypeStruct(() if q is None else (q,), jnp.int32, sharding=c_sh),
+    ]
+    compiled = _cut_target.lower(*shapes, cond, sharding).compile()
+    (out,) = jax.tree.leaves(compiled.out_info)
+    assert out.shape == ((rows,) if q is None else (q, rows))
+    assert out.dtype == (jnp.int8 if cond is None else jnp.int32)
+    if layout == "feat":
+        assert "all-reduce" in compiled.as_text()  # the owner's column

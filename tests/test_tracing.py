@@ -83,7 +83,7 @@ def test_spans_of_a_streamed_fit(tmp_path, data, criterion, mode):
     sel, spans = _traced(tmp_path, lambda: _fit(data, criterion, mode))
     io = sel.result_.io
     by = _by_name(spans)
-    expected = set(tracing.SPANS)
+    expected = set(tracing.SPANS) - {tracing.CUT}  # cut in resident passes
     if mode == "sync":
         expected.discard(tracing.FEED_WAIT)  # nothing to wait on
     assert set(by) == expected
@@ -100,6 +100,7 @@ def test_spans_of_a_streamed_fit(tmp_path, data, criterion, mode):
     kinds = {p.args["pass"]: p.args["kind"] for p in passes}
     cond = "feature_cond" if criterion == "jmi" else "feature"
     assert kinds == {p: "class" if p == 0 else cond for p in range(SELECT)}
+    assert all(p.args["resident"] == 0 for p in passes)
     assert all(_inside(p, fit) for p in passes)
     pass_of = {p.args["pass"]: p for p in passes}
 
@@ -153,6 +154,65 @@ def test_host_round_trip_counters(data, criterion, mode):
     red_pass = BLOCKS * BLOCK * (COLS + target + 1)
     vectors = 4 * COLS * terms
     assert io["h2d_bytes"] == rel_pass + (SELECT - 1) * red_pass + vectors
+
+
+def _resident_h2d(blocks, block, cols, terms, passes, target=1):
+    """Bytes a resident fit places: one pass of blocks (X, the class
+    target, a validity byte a row), ``terms`` float32 vectors, and the
+    int32 id of the column each later pass cuts its target by."""
+    return (
+        blocks * block * (cols + target + 1) + 4 * cols * terms
+        + 4 * (passes - 1)
+    )
+
+
+def _resident(monkeypatch):
+    import repro.dist.streaming as dist
+
+    monkeypatch.setattr(dist, "resident_budget", lambda devices: 1 << 40)
+
+
+@pytest.mark.parametrize("mode", sorted(MODES))
+@pytest.mark.parametrize("criterion", ["mid", "jmi"])
+def test_resident_round_trip_counters(data, monkeypatch, criterion, mode):
+    _resident(monkeypatch)
+    io = _fit(data, criterion, mode).result_.io
+    terms = 1 + (SELECT - 1) * (2 if criterion == "jmi" else 1)
+    assert io["resident_passes"] == SELECT - 1
+    assert io["host_syncs"] == terms + SELECT
+    # the passes after the first cut their targets on the device
+    assert io["h2d_bytes"] == _resident_h2d(BLOCKS, BLOCK, COLS, terms, SELECT)
+    # the benchmark's geometries at L = 10: corral_tall_1m (16 blocks of
+    # 65,536 x 1,000 int8) under mid and jmi, corral_wide_50k (4 blocks
+    # of 2,048 x 50,000)
+    assert _resident_h2d(16, 65_536, 1_000, 10, 10) == 1_050_713_188
+    assert _resident_h2d(16, 65_536, 1_000, 19, 10) == 1_050_749_188
+    assert _resident_h2d(4, 2_048, 50_000, 10, 10) == 411_616_420
+
+
+@pytest.mark.parametrize("criterion", ["mid", "jmi"])
+def test_spans_of_a_resident_fit(tmp_path, data, monkeypatch, criterion):
+    _resident(monkeypatch)
+    sel, spans = _traced(tmp_path, lambda: _fit(data, criterion))
+    by = _by_name(spans)
+    passes = {p.args["pass"]: p for p in by[tracing.PASS]}
+    assert {p: s.args["resident"] for p, s in passes.items()} == {
+        p: int(p > 0) for p in range(SELECT)
+    }
+    # only the first pass reads, stages and places; every later pass
+    # dispatches the device cut of its target; every pass accumulates
+    first = {(0, b) for b in range(BLOCKS)}
+    later = {(p, b) for p in range(1, SELECT) for b in range(BLOCKS)}
+    for name, want in [
+        (tracing.READ, first), (tracing.STAGE, first),
+        (tracing.PLACE, first), (tracing.CUT, later),
+        (tracing.ACCUMULATE, first | later),
+    ]:
+        got = [(s.args["pass"], s.args["block"]) for s in by[name]]
+        assert sorted(got) == sorted(want), name
+    for s in by[tracing.STAGE] + by[tracing.CUT] + by[tracing.ACCUMULATE]:
+        assert _inside(s, passes[s.args["pass"]])
+    assert sel.result_.io["resident_passes"] == SELECT - 1
 
 
 @pytest.mark.parametrize("criterion", ["mid", "jmi"])
