@@ -105,7 +105,10 @@ per finalize term and one per pick's objective) and ``h2d_bytes`` (host
 arrays placed on the device: every block triple, plus the vectors the
 greedy loop folds, and the column ids a resident pass cuts its targets
 by), and ``resident_passes``, the passes counted from device-resident
-blocks.  Each layer boundary is a ``mrmr.*`` profiler span
+blocks.  A fit that weighs residency also reports the bytes its placed
+blocks would take a device (``resident_need_bytes``) and the budget they
+were held to (``resident_budget_bytes``, where the backend reports
+memory).  Each layer boundary is a ``mrmr.*`` profiler span
 (:mod:`repro.runtime.tracing`).
 """
 
@@ -392,6 +395,11 @@ class _PassIO:
         self.host_syncs = 0
         self.h2d_bytes = 0
         self.resident_passes = 0
+        # Set where the fit weighs keeping its blocks on the device: the
+        # bytes they would take a device, and the budget they were held to
+        # (None where the backend reports no memory).
+        self.resident_need_bytes = None
+        self.resident_budget_bytes = None
 
     def count(self, raw_blocks):
         for X_blk, y_blk in raw_blocks:
@@ -419,7 +427,7 @@ class _PassIO:
         return np.array(x, np.float32)
 
     def as_dict(self) -> dict:
-        return dict(
+        out = dict(
             passes=self.passes,
             blocks_read=self.blocks_read,
             bytes_read=self.bytes_read,
@@ -428,6 +436,10 @@ class _PassIO:
             h2d_bytes=self.h2d_bytes,
             resident_passes=self.resident_passes,
         )
+        for key in ("resident_need_bytes", "resident_budget_bytes"):
+            if getattr(self, key) is not None:
+                out[key] = getattr(self, key)
+        return out
 
 
 def _score_pass(
@@ -585,21 +597,29 @@ def _max_passes(
 
 
 def _resident_blocks(
-    placer: BlockPlacer, source: DataSource, crit: Criterion, num_select: int
+    placer: BlockPlacer,
+    source: DataSource,
+    crit: Criterion,
+    num_select: int,
+    io: _PassIO,
 ) -> ResidentBlocks | None:
     """Keep this fit's blocks on the device when it makes more than one
     pass and the whole placed dataset fits :func:`~repro.dist.streaming.
     resident_budget` of every device it lands on (its bytes then stay
     promised until the fit ends); else None, and every pass streams.  A
     source whose feature dtype is unknown before a read is counted at 8
-    bytes a value."""
+    bytes a value.  A fit of more than one pass records on ``io`` the
+    bytes a device would hold and, where the backend reports memory, the
+    budget they were held to."""
     if num_select < 2 or not crit.needs_redundancy:
         return None
     dtype = source.feature_dtype
     itemsize = 8 if dtype is None else np.dtype(dtype).itemsize
-    return ResidentBlocks.reserve(
-        placer, placer.resident_bytes(source.num_obs, itemsize)
+    io.resident_need_bytes = placer.resident_bytes(source.num_obs, itemsize)
+    resident, io.resident_budget_bytes = ResidentBlocks.reserve(
+        placer, io.resident_need_bytes
     )
+    return resident
 
 
 def _greedy_select(
@@ -834,11 +854,11 @@ def mrmr_streaming(
     # including a spill-cached binned source, whose cache already holds
     # the codes — streams the source itself.
     block_src = binned.base if binned is not None else source
+    io = _PassIO()
     resident = (
         None if binned is not None
-        else _resident_blocks(placer, source, crit, num_select)
+        else _resident_blocks(placer, source, crit, num_select, io)
     )
-    io = _PassIO()
     next_raw, reader = _pass_reader(
         block_src, placer.block_obs, io, readahead,
         _max_passes(crit, num_select, resident),

@@ -396,19 +396,23 @@ class ResidentBlocks:
         self._promised = {d: nbytes for d in placer.devices} if nbytes else {}
 
     @classmethod
-    def reserve(cls, placer: BlockPlacer, nbytes: int) -> ResidentBlocks | None:
-        """Resident blocks for a fit needing ``nbytes`` a device, when they
-        fit :func:`resident_budget` of every device of ``placer``; the
-        bytes stay promised until :meth:`delete`.  None where they do not
-        fit, or the backend reports no memory: that fit streams."""
+    def reserve(
+        cls, placer: BlockPlacer, nbytes: int
+    ) -> tuple[ResidentBlocks | None, int | None]:
+        """``(blocks, budget)``: the :func:`resident_budget` of the devices
+        of ``placer`` that a fit needing ``nbytes`` a device was held to,
+        and its resident blocks where they fit it; the bytes stay promised
+        until :meth:`delete`.  The blocks are None where they do not fit,
+        or the backend reports no memory (the budget is then None too):
+        that fit streams."""
         with _RESERVE_LOCK:
             budget = resident_budget(placer.devices)
             if budget is None or nbytes > budget:
-                return None
+                return None, budget
             kept = cls(placer, nbytes)
             for d, n in kept._promised.items():
                 _RESERVED[d] = _RESERVED.get(d, 0) + n
-        return kept
+        return kept, budget
 
     def keep(self, placed):
         """Pass-through of the first pass's placed triples, keeping each."""

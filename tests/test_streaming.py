@@ -960,6 +960,12 @@ class TestResidentBlocks:
         got = self.fit(data, source, **kw)
         self.assert_same(got, streamed)
         io, was = got.result_.io, streamed.result_.io
+        if case in ("over_budget", "at_budget"):
+            assert io["resident_need_bytes"] == need
+            assert io["resident_budget_bytes"] == budget
+        else:  # a fit that never weighs residency
+            assert "resident_need_bytes" not in io
+            assert "resident_budget_bytes" not in io
         if case == "at_budget":
             assert io["resident_passes"] == io["passes"] - 1 > 0
             return
@@ -1036,6 +1042,53 @@ class TestResidentBlocks:
         # and their bytes no longer promised
         assert held and all(a.is_deleted() for t in held for a in t)
         assert not dist._RESERVED
+
+    @pytest.mark.parametrize("criterion", ["mid", "jmi"])
+    def test_fit_above_its_budget_streams(self, data, monkeypatch, criterion):
+        # A device that reports its memory, with half of it free one byte
+        # short of the placed dataset: the fit streams every pass, the
+        # partial last block included, and says how far above it sat.
+        need = 4 * self.BLOCK * (self.COLS + 8 + 1)  # 4 blocks, the last of 232 rows
+        assert BlockPlacer(self.BLOCK, num_features=self.COLS).resident_bytes(
+            self.ROWS, 1
+        ) == need
+        X, y = data
+        plain = self.fit(data, criterion=criterion).result_.io
+        assert plain["resident_need_bytes"] == need
+        assert "resident_budget_bytes" not in plain  # the CPU reports none
+
+        monkeypatch.setattr(
+            type(jax.devices()[0]), "memory_stats",
+            lambda device: dict(bytes_limit=2 * (need - 1), bytes_in_use=0),
+        )
+        streamed = self.fit(data, criterion=criterion)
+        io = streamed.result_.io
+        assert io["resident_passes"] == 0
+        assert io["blocks_read"] == io["passes"] * 4 and io["passes"] > 1
+        assert io["bytes_read"] == io["passes"] * (X.nbytes + y.nbytes)
+        assert io["resident_need_bytes"] == need
+        assert io["resident_budget_bytes"] == need - 1
+
+        monkeypatch.setattr(
+            type(jax.devices()[0]), "memory_stats",
+            lambda device: dict(bytes_limit=2 * need, bytes_in_use=0),
+        )
+        resident = self.fit(data, criterion=criterion)
+        assert resident.result_.io["resident_passes"] == io["passes"] - 1
+        assert resident.result_.io["resident_budget_bytes"] == need
+        self.assert_same(streamed, resident)
+
+        from repro.core.mrmr import mrmr_reference
+
+        ref = mrmr_reference(
+            jax.numpy.asarray(X.T), jax.numpy.asarray(y), self.SELECT,
+            MIScore(3, 2), criterion=criterion,
+        )
+        np.testing.assert_array_equal(streamed.selected_, ref.selected)
+        np.testing.assert_array_equal(streamed.scores_, ref.relevance)
+        # the reference folds its redundancy sums in another order: a few
+        # float32 roundings of gains up to 0.3
+        np.testing.assert_allclose(streamed.gains_, ref.gains, rtol=0, atol=1e-7)
 
     def test_concurrent_fits_share_the_budget(self, data, monkeypatch):
         # A fit places its resident blocks over the whole of its first

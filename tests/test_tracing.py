@@ -156,6 +156,41 @@ def test_host_round_trip_counters(data, criterion, mode):
     assert io["h2d_bytes"] == rel_pass + (SELECT - 1) * red_pass + vectors
 
 
+def _streamed_h2d(rows, block, cols, terms, passes, red_target=1):
+    """Bytes a streamed fit places: every pass places each block padded to
+    ``block`` rows, the partial last one too (X, its target, a validity
+    byte a row; ``red_target`` bytes a target in the redundancy passes),
+    then ``terms`` float32 vectors."""
+    blocks = -(-rows // block)
+    per_row = (cols + 1 + 1) + (passes - 1) * (cols + red_target + 1)
+    return blocks * block * per_row + 4 * cols * terms
+
+
+def _streamed_read(rows, cols, passes):
+    """Bytes a streamed fit reads of int8 X and y: every row, every pass."""
+    return passes * rows * (cols + 1)
+
+
+@pytest.mark.parametrize("criterion", ["mid", "jmi"])
+def test_streamed_counters_of_a_ragged_fit(data, criterion):
+    # 1000 rows in blocks of 300: three whole ones and one of 100 rows
+    io = MRMRSelector(
+        num_select=SELECT, criterion=criterion, block_obs=300, prefetch=0,
+    ).fit(ArraySource(*data)).result_.io
+    terms = 1 + (SELECT - 1) * (2 if criterion == "jmi" else 1)
+    target = 4 if criterion == "jmi" else 1
+    assert io["resident_passes"] == 0 and io["passes"] == SELECT
+    assert io["blocks_read"] == SELECT * 4
+    assert io["bytes_read"] == _streamed_read(ROWS, COLS, SELECT)
+    assert io["h2d_bytes"] == _streamed_h2d(
+        ROWS, 300, COLS, terms, SELECT, target
+    )
+    # corral_tall_10m at L = 5, mid: 152 blocks of 65,536 rows and one of
+    # 38,528, five passes
+    assert _streamed_h2d(10_000_000, 65_536, 1_000, 5, 5) == 50_235_330_080
+    assert _streamed_read(10_000_000, 1_000, 5) == 50_050_000_000
+
+
 def _resident_h2d(blocks, block, cols, terms, passes, target=1):
     """Bytes a resident fit places: one pass of blocks (X, the class
     target, a validity byte a row), ``terms`` float32 vectors, and the
