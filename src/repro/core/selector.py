@@ -40,7 +40,12 @@ from repro.core.criteria import Criterion, resolve_criterion
 from repro.core.mrmr import MRMRResult, WarmJitCache
 from repro.core.scores import MIScore, PearsonMIScore, ScoreFn, _OOR
 from repro.data.binning import BinnedSource
-from repro.data.sources import ArraySource, DataSource
+from repro.data.sources import (
+    ArraySource,
+    DataSource,
+    SourceStats,
+    needs_category_scan,
+)
 from repro.dist.meshes import factor_mesh, make_mesh
 from repro.dist.sharding import axes_tuple as _axes_tuple, mesh_extent
 from repro.dist.streaming import effective_block_obs, resolve_prefetch
@@ -69,6 +74,14 @@ def check_num_select(num_select, n_features: int) -> None:
             f"num_select={num_select} out of range: need "
             f"1 <= num_select <= num_features ({n_features})"
         )
+
+
+def score_of_stats(st: SourceStats) -> ScoreFn:
+    """The default score of data whose stats are ``st``: exact MI sized
+    by its category counts where it is discrete, else Pearson-MI."""
+    if st.discrete:
+        return MIScore(num_values=st.num_values, num_classes=st.num_classes)
+    return PearsonMIScore()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -689,10 +702,8 @@ class MRMRSelector:
     def _resolve_source_score(self, source: DataSource) -> ScoreFn:
         if self.score is not None:
             return self.score
-        st = source.stats(self.block_obs)  # scan honours the memory knob
-        if st.discrete:
-            return MIScore(num_values=st.num_values, num_classes=st.num_classes)
-        return PearsonMIScore()
+        # the scan honours the memory knob
+        return score_of_stats(source.stats(self.block_obs))
 
     def _continuous_mi_error(self, what: str) -> ValueError:
         return ValueError(
@@ -914,6 +925,12 @@ class MRMRSelector:
             engine = get_engine("streaming")
             res = engine(source, None, num_select=self.num_select, plan=plan,
                          mesh=mesh)
+            if plan.score is None:
+                # The engine sized the default score and left its stats on
+                # the source: this reads them back without I/O.
+                plan = dataclasses.replace(
+                    plan, score=self._resolve_source_score(source)
+                )
             return self._finish_fit(res, plan, mesh, source.num_features)
 
     def _plan_source(self, source: DataSource):
@@ -929,6 +946,11 @@ class MRMRSelector:
         source = self._maybe_bin_source(source)
         if isinstance(source, BinnedSource):
             score = self._bin_score(source)
+        elif self.score is None and needs_category_scan(source):
+            # Exact MI whose category counts nothing has read yet: the
+            # engine takes them from the blocks it keeps on the device, or
+            # scans where it streams.  An MIScore passes every check below.
+            score = None
         else:
             score = self._resolve_source_score(source)
             if isinstance(score, MIScore) and not self._source_is_discrete(
@@ -939,9 +961,10 @@ class MRMRSelector:
                 raise self._continuous_mi_error("the source")
         # Conditional criteria (jmi/cmim) need a score with a class-
         # conditioned decomposition — fail before the first I/O pass.
-        mrmr_mod.check_conditional_support(
-            score, resolve_criterion(self.criterion)
-        )
+        if score is not None:
+            mrmr_mod.check_conditional_support(
+                score, resolve_criterion(self.criterion)
+            )
         plan = self._resolve_stream_plan(source, score)
         if isinstance(source, BinnedSource):
             plan = dataclasses.replace(plan, bins=source.bins)
@@ -1048,6 +1071,7 @@ __all__ = [
     "check_num_select",
     "plan_selection",
     "register_engine",
+    "score_of_stats",
     "get_engine",
     "available_encodings",
     "build_engine_fn",

@@ -69,7 +69,12 @@ the placed blocks of its relevance pass on the device
 device memory the backend reports less what running fits have been
 promised, and counts every later pass from them with its target cut on
 the device — the same accumulate programs on the
-same blocks in the same order, so selections stay bitwise.  The blocks
+same blocks in the same order, so selections stay bitwise.  Where such a
+fit is left to size its default MI score and no stats of the source are
+memoised, its first pass places the blocks before counting anything, the
+category counts reduce from them on the device in place of a stats scan
+of the source, and the relevance pass counts from them too: one read of
+the source a fit.  The blocks
 are freed when the fit returns or raises.  Fits above the budget,
 multi-host fits, the fused binned path and backends that report no
 memory (the CPU) stream every pass.
@@ -104,8 +109,9 @@ counts the host round trips: ``host_syncs`` (device-to-host copies, one
 per finalize term and one per pick's objective) and ``h2d_bytes`` (host
 arrays placed on the device: every block triple, plus the vectors the
 greedy loop folds, and the column ids a resident pass cuts its targets
-by), and ``resident_passes``, the passes counted from device-resident
-blocks.  A fit that weighs residency also reports the bytes its placed
+by), ``resident_passes``, the passes counted from device-resident
+blocks, and ``resident_stats``, 1 where the default score was sized from
+them.  A fit that weighs residency also reports the bytes its placed
 blocks would take a device (``resident_need_bytes``) and the budget they
 were held to (``resident_budget_bytes``, where the backend reports
 memory).  Each layer boundary is a ``mrmr.*`` profiler span
@@ -127,10 +133,20 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core.criteria import Criterion, resolve_criterion
 from repro.core.mrmr import MRMRResult, WarmJitCache, check_conditional_support
 from repro.core.scores import MIScore, ScoreFn
-from repro.core.selector import check_num_select, register_engine
+from repro.core.selector import (
+    check_num_select,
+    register_engine,
+    score_of_stats,
+)
 from repro.data.binning import BinnedSource, _as_class_labels
 from repro.data.block_cache import BlockCacheSource
-from repro.data.sources import DataSource, ShardSource, as_source
+from repro.data.sources import (
+    DataSource,
+    ShardSource,
+    SourceStats,
+    as_source,
+    needs_category_scan,
+)
 from repro.dist.multihost import HostCollectives, HostShardSpec
 from repro.dist.streaming import (
     BlockPlacer,
@@ -395,6 +411,8 @@ class _PassIO:
         self.host_syncs = 0
         self.h2d_bytes = 0
         self.resident_passes = 0
+        # 1 where the default score was sized from device-resident blocks
+        self.resident_stats = 0
         # Set where the fit weighs keeping its blocks on the device: the
         # bytes they would take a device, and the budget they were held to
         # (None where the backend reports no memory).
@@ -435,6 +453,7 @@ class _PassIO:
             host_syncs=self.host_syncs,
             h2d_bytes=self.h2d_bytes,
             resident_passes=self.resident_passes,
+            resident_stats=self.resident_stats,
         )
         for key in ("resident_need_bytes", "resident_budget_bytes"):
             if getattr(self, key) is not None:
@@ -506,32 +525,17 @@ def _score_pass(
         io.note_state(state)
         cond_classes = score.num_classes if cond else None
 
-        def staged_blocks():
-            for block, (X_blk, y_blk) in enumerate(io.count(raw_pass)):
-                with tracing.span(tracing.STAGE, block=block, **ids):
-                    if binner is not None:
-                        X_blk = np.asarray(X_blk, np.float32)
-                    target = _extract_target(
-                        X_blk, y_blk, target_cols, binner, cond_classes
-                    )
-                    staged = placer.stage(X_blk, target)
-                yield staged
-
         if from_resident:
             io.resident_passes += 1
-            (cols,) = io.note_placed([placer.place_ids(target_cols)])
+            cols = None
+            if target_cols is not None:
+                (cols,) = io.note_placed([placer.place_ids(target_cols)])
             placed = resident.triples(cols, cond_classes, **ids)
         else:
-            if prefetch > 0:
-                placed = PrefetchPlacer(placer, depth=prefetch).stream(
-                    staged_blocks(), **ids
-                )
-            else:
-                placed = (
-                    placer.place(staged, block=block, **ids)
-                    for block, staged in enumerate(staged_blocks())
-                )
-            placed = map(io.note_placed, placed)
+            placed = _placed_blocks(
+                raw_pass, placer, target_cols, prefetch, io, ids, binner,
+                cond_classes,
+            )
             if resident is not None and target_cols is None:
                 placed = resident.keep(placed)
         for block, triple in enumerate(placed):
@@ -555,6 +559,90 @@ def _score_pass(
             if batch is None:
                 return io.to_host(score.finalize(state))[:n]
             return io.to_host(jax.vmap(score.finalize)(state))[:, :n]
+
+
+def _placed_blocks(
+    raw_pass, placer: BlockPlacer, target_cols, prefetch: int, io: _PassIO,
+    ids: dict, binner=None, cond_classes: int | None = None,
+):
+    """The placed ``(X, target, valid)`` triple of each raw host block of
+    ``raw_pass``: read, target extracted, staged and placed, each block's
+    bytes counted on ``io``; with ``prefetch`` the reads and staging run on
+    a :class:`~repro.dist.streaming.PrefetchPlacer` thread."""
+
+    def staged_blocks():
+        for block, (X_blk, y_blk) in enumerate(io.count(raw_pass)):
+            with tracing.span(tracing.STAGE, block=block, **ids):
+                if binner is not None:
+                    X_blk = np.asarray(X_blk, np.float32)
+                target = _extract_target(
+                    X_blk, y_blk, target_cols, binner, cond_classes
+                )
+                staged = placer.stage(X_blk, target)
+            yield staged
+
+    if prefetch > 0:
+        placed = PrefetchPlacer(placer, depth=prefetch).stream(
+            staged_blocks(), **ids
+        )
+    else:
+        placed = (
+            placer.place(staged, block=block, **ids)
+            for block, staged in enumerate(staged_blocks())
+        )
+    return map(io.note_placed, placed)
+
+
+@jax.jit
+def _widen_extrema(extrema, X, y):
+    """``(x_max, x_min, y_max, y_min)`` widened by one placed block.  Pad
+    rows and columns hold zeros, which the ranges take in anyway."""
+    x_max, x_min, y_max, y_min = extrema
+    return (
+        jnp.maximum(x_max, X.max()), jnp.minimum(x_min, X.min()),
+        jnp.maximum(y_max, y.max()), jnp.minimum(y_min, y.min()),
+    )
+
+
+def _scanned_score(source: DataSource, block_obs: int, fit: int) -> ScoreFn:
+    """The default score from the source's stats scan, which runs in a
+    ``mrmr.plan`` span."""
+    with tracing.span(tracing.PLAN, fit=fit):
+        return score_of_stats(source.stats(block_obs))
+
+
+def _score_of_kept_blocks(
+    raw_pass, source: DataSource, placer: BlockPlacer, prefetch: int,
+    io: _PassIO, resident: ResidentBlocks,
+) -> ScoreFn:
+    """The default score of a fit that keeps its blocks on the device,
+    sized from them in place of a stats scan of the source.
+
+    The first pass reads, stages and places ``raw_pass`` into
+    ``resident``, counting nothing.  Then, in a ``mrmr.plan`` span, the
+    extremes of the kept features and classes reduce on the device (across
+    every chip the blocks are sharded over) and come to the host in one
+    sync.  The stats they give are memoised on ``source`` as its own scan
+    would leave them, so a later fit of the same data reads none."""
+    ids = {"fit": io.fit, "pass": io.passes}
+    for _ in resident.keep(
+        _placed_blocks(raw_pass, placer, None, prefetch, io, ids)
+    ):
+        pass
+    with tracing.span(tracing.PLAN, fit=io.fit):
+        X, y, _ = resident.blocks[0]
+        extrema = (
+            jnp.zeros((), X.dtype), jnp.zeros((), X.dtype),
+            jnp.zeros((), y.dtype), jnp.zeros((), y.dtype),
+        )
+        for X, y, _ in resident.blocks:
+            extrema = _widen_extrema(extrema, X, y)
+        io.host_syncs += 1
+        x_max, x_min, y_max, y_min = map(int, jax.device_get(extrema))
+        st = SourceStats.from_extrema(True, x_max, x_min, y_max, y_min)
+    source.remember_stats(st)
+    io.resident_stats = 1
+    return score_of_stats(st)
 
 
 def _pass_reader(
@@ -717,7 +805,12 @@ def mrmr_streaming(
     Args:
       source: a ``DataSource`` (or an ``(X, y)`` pair to wrap).
       num_select: L, number of features to pick.
-      score: a streaming-capable ``ScoreFn`` (``supports_streaming``).
+      score: a streaming-capable ``ScoreFn`` (``supports_streaming``), or
+        None for the default: exact MI sized by the data's category counts
+        where its dtypes are integral, else Pearson-MI.  Where those counts
+        are not memoised and the fit keeps its blocks on the device, they
+        come from the placed blocks (:func:`_score_of_kept_blocks`), so the
+        source is read once; otherwise from ``source.stats()``.
       block_obs: observations per device block — the peak-memory knob
         (rounded up to the mesh's observation extent).
       mesh / obs_axes / feat_axes: shard each block over the observation
@@ -757,17 +850,6 @@ def mrmr_streaming(
     """
     crit = resolve_criterion(criterion)
     source = as_source(*source) if isinstance(source, tuple) else as_source(source)
-    if not score.supports_streaming:
-        raise ValueError(
-            f"{type(score).__name__} cannot stream: it has no "
-            "sufficient-statistics decomposition (init_state/accumulate/"
-            "finalize). Materialise the data and use an in-memory engine."
-        )
-    # JMI/CMIM need class-conditioned pair statistics; fail before any
-    # I/O if the score can't produce them.  Non-conditional criteria keep
-    # the exact pre-refactor pass shapes and state bytes.
-    check_conditional_support(score, crit)
-    needs_cond = crit.needs_redundancy and crit.needs_conditional_redundancy
     n = source.num_features
     check_num_select(num_select, n)
     prefetch = resolve_prefetch(prefetch)
@@ -776,8 +858,25 @@ def mrmr_streaming(
         raise ValueError(f"batch_candidates must be >= 1, got {q}")
     if readahead < 0:
         raise ValueError(f"readahead must be >= 0, got {readahead}")
+    multihost = shards is not None and not shards.is_single_host
+    if score is None and (multihost or not needs_category_scan(source)):
+        score = _scanned_score(source, block_obs, tracing.fit_id())
+    # A score still None is an MIScore to be sized, which streams and
+    # supports every criterion.
+    if score is not None and not score.supports_streaming:
+        raise ValueError(
+            f"{type(score).__name__} cannot stream: it has no "
+            "sufficient-statistics decomposition (init_state/accumulate/"
+            "finalize). Materialise the data and use an in-memory engine."
+        )
+    # JMI/CMIM need class-conditioned pair statistics; fail before any
+    # I/O if the score can't produce them.  Non-conditional criteria keep
+    # the exact pre-refactor pass shapes and state bytes.
+    if score is not None:
+        check_conditional_support(score, crit)
+    needs_cond = crit.needs_redundancy and crit.needs_conditional_redundancy
 
-    if shards is not None and not shards.is_single_host:
+    if multihost:
         return _mrmr_streaming_multihost(
             source,
             num_select,
@@ -798,6 +897,7 @@ def mrmr_streaming(
 
     # A caller-wrapped BlockCacheSource reports its counters on the result
     # the same as an engine-built one.
+    given = source
     spill: BlockCacheSource | None = (
         source if isinstance(source, BlockCacheSource) else None
     )
@@ -818,36 +918,13 @@ def mrmr_streaming(
     # jnp elsewhere) directly ahead of the contingency sum.  The sketch
     # pass (memoised by fingerprint) happens here, before the first
     # scoring pass.  Any other score falls back to host-side encoding
-    # through the wrapper's normal iter_blocks.
+    # through the wrapper's normal iter_blocks.  (A score left to size is
+    # never a binned source's: its dtypes are integral.)
     binned = (
         source
         if isinstance(source, BinnedSource) and isinstance(score, MIScore)
         else None
     )
-    num_edges = None
-    if binned is not None:
-        edges = binned.binner.edges_
-        num_edges = edges.shape[1]
-        edges_dev = _placed_edges(edges, placer)
-
-        def _wrap(base_fn):
-            return lambda state, X_block, target, valid: base_fn(
-                state, X_block, target, valid, edges_dev
-            )
-
-        acc_fn = _wrap(_cached_acc_fn(score, placer, mesh, num_edges=num_edges))
-        acc_fn_q = (
-            _wrap(
-                _cached_acc_fn(
-                    score, placer, mesh, num_edges=num_edges, batch=q
-                )
-            )
-            if q > 1
-            else None
-        )
-    else:
-        acc_fn = _cached_acc_fn(score, placer, mesh)
-        acc_fn_q = _cached_acc_fn(score, placer, mesh, batch=q) if q > 1 else None
 
     # Raw block production: the fused binned path streams the *base*
     # source's float blocks (the device encodes them); everything else —
@@ -859,6 +936,8 @@ def mrmr_streaming(
         None if binned is not None
         else _resident_blocks(placer, source, crit, num_select, io)
     )
+    if score is None and resident is None:
+        score = _scanned_score(given, block_obs, io.fit)
     next_raw, reader = _pass_reader(
         block_src, placer.block_obs, io, readahead,
         _max_passes(crit, num_select, resident),
@@ -866,16 +945,27 @@ def mrmr_streaming(
     if reader is not None:
         prefetch = 0  # the reader thread is the producer; stage at consume
 
-    def run_pass(target_cols, batch=None):
-        raw = None if resident is not None and resident.complete else next_raw()
-        return _score_pass(
-            raw, source, score, acc_fn if batch is None else acc_fn_q,
-            placer, target_cols, prefetch, io, binned, batch,
-            conditional=needs_cond and target_cols is not None,
-            resident=resident,
-        )
-
     try:
+        if score is None:
+            # The relevance pass places its blocks before it counts, so
+            # the score's category counts come from them, not a scan.
+            score = _score_of_kept_blocks(
+                next_raw(), given, placer, prefetch, io, resident
+            )
+        acc_fn, acc_fn_q = _acc_fns(score, placer, mesh, q, binned)
+
+        def run_pass(target_cols, batch=None):
+            raw = (
+                None if resident is not None and resident.complete
+                else next_raw()
+            )
+            return _score_pass(
+                raw, source, score, acc_fn if batch is None else acc_fn_q,
+                placer, target_cols, prefetch, io, binned, batch,
+                conditional=needs_cond and target_cols is not None,
+                resident=resident,
+            )
+
         rel, selected, gains = _greedy_select(
             run_pass, crit, n, num_select, q, io
         )
@@ -894,6 +984,36 @@ def mrmr_streaming(
         criterion=crit.name,
         engine="streaming",
         io=io_report,
+    )
+
+
+def _acc_fns(
+    score: ScoreFn, placer: BlockPlacer, mesh: Mesh | None, q: int,
+    binned: BinnedSource | None,
+):
+    """-> ``(acc_fn, acc_fn_q)``: the accumulate of a single-target pass
+    and, where ``q > 1``, of a ``q``-wide batched one (else None).  A
+    ``binned`` source's accumulates take its fitted edges, placed once, and
+    encode the raw float block on the device."""
+    if binned is None:
+        return (
+            _cached_acc_fn(score, placer, mesh),
+            _cached_acc_fn(score, placer, mesh, batch=q) if q > 1 else None,
+        )
+    edges = binned.binner.edges_
+    num_edges = edges.shape[1]
+    edges_dev = _placed_edges(edges, placer)
+
+    def with_edges(base_fn):
+        return lambda state, X_block, target, valid: base_fn(
+            state, X_block, target, valid, edges_dev
+        )
+
+    return (
+        with_edges(_cached_acc_fn(score, placer, mesh, num_edges=num_edges)),
+        with_edges(
+            _cached_acc_fn(score, placer, mesh, num_edges=num_edges, batch=q)
+        ) if q > 1 else None,
     )
 
 

@@ -162,6 +162,10 @@ class BlockCacheSource(DataSource):
         dt = self.base.feature_dtype
         return self._spill_dtype if self._spill_dtype is not None else dt
 
+    @property
+    def target_dtype(self) -> np.dtype | None:
+        return self.base.target_dtype  # targets spill as they are read
+
     def fingerprint(self) -> str:
         # Same content, same address: the cache changes where blocks come
         # from, never what they hold — result-cache keys must coalesce.

@@ -62,6 +62,11 @@ def clear_stats_memo() -> None:
         _STATS_MEMO.clear()
 
 
+def is_integral(dtype) -> bool:
+    """Whether blocks of ``dtype`` hold categories (integers or booleans)."""
+    return np.issubdtype(dtype, np.integer) or dtype == np.bool_
+
+
 @dataclasses.dataclass(frozen=True)
 class SourceStats:
     """Streaming-scan metadata used to auto-resolve a score function."""
@@ -69,6 +74,27 @@ class SourceStats:
     discrete: bool      # X and y both integral -> exact-MI territory
     num_values: int     # d_v: 1 + max feature category (0 if continuous)
     num_classes: int    # d_c: 1 + max class label (0 if continuous)
+
+    @classmethod
+    def from_extrema(
+        cls, discrete: bool, x_max: int, x_min: int, y_max: int, y_min: int
+    ) -> "SourceStats":
+        """The stats of data whose feature and target values span
+        ``[x_min, x_max]`` and ``[y_min, y_max]``, each range widened to
+        take in 0.  Discrete data with a negative category raises: it
+        one-hots to an all-zero row, so the observation would silently
+        vanish from every contingency count and the MI be wrong with no
+        error anywhere."""
+        if not discrete:
+            return cls(discrete=False, num_values=0, num_classes=0)
+        if x_min < 0 or y_min < 0:
+            raise ValueError(
+                "negative category values in discrete source "
+                f"(min feature value {x_min}, min target value {y_min}): "
+                "one-hot contingency counts drop them silently; remap "
+                "categories to 0..K-1 before fitting"
+            )
+        return cls(discrete=True, num_values=x_max + 1, num_classes=y_max + 1)
 
 
 def _rechunked(chunks: Iterator[Block], block_obs: int) -> Iterator[Block]:
@@ -119,6 +145,10 @@ class DataSource:
         hint means continuous, any other hint means discrete (matching
         the dtype rule in :meth:`stats`)."""
         return None
+
+    # Static dtype of the target blocks, when knowable WITHOUT I/O (None
+    # otherwise); a subclass sets or overrides it where it can.
+    target_dtype: "np.dtype | None" = None
 
     # -- identity --------------------------------------------------------
 
@@ -207,6 +237,29 @@ class DataSource:
         selection service builds them) reuses the scan instead of paying
         a full pass of I/O per fit.
         """
+        st = self.cached_stats()
+        if st is not None:
+            return st
+        x_max = y_max = 0
+        x_min = y_min = 0
+        discrete = True
+        for X, y in self.iter_blocks(block_obs):
+            discrete = (
+                discrete and is_integral(X.dtype) and is_integral(y.dtype)
+            )
+            if not discrete:
+                break  # dtype settles it; don't burn a full pass of I/O
+            x_max = max(x_max, int(X.max(initial=0)))
+            y_max = max(y_max, int(y.max(initial=0)))
+            x_min = min(x_min, int(X.min(initial=0)))
+            y_min = min(y_min, int(y.min(initial=0)))
+        st = SourceStats.from_extrema(discrete, x_max, x_min, y_max, y_min)
+        self.remember_stats(st)
+        return st
+
+    def cached_stats(self) -> "SourceStats | None":
+        """The memoised :meth:`stats` of this source or of another with
+        its fingerprint, or None where neither has been taken: no I/O."""
         cached = getattr(self, "_stats", None)
         if cached is not None:
             return cached
@@ -217,42 +270,18 @@ class DataSource:
                 _STATS_MEMO.move_to_end(fp)
         if memo is not None:
             object.__setattr__(self, "_stats", memo)
-            return memo
-        x_max = y_max = 0
-        x_min = y_min = 0
-        discrete = True
-        for X, y in self.iter_blocks(block_obs):
-            discrete = discrete and (
-                np.issubdtype(X.dtype, np.integer) or X.dtype == np.bool_
-            ) and (np.issubdtype(y.dtype, np.integer) or y.dtype == np.bool_)
-            if not discrete:
-                break  # dtype settles it; don't burn a full pass of I/O
-            x_max = max(x_max, int(X.max(initial=0)))
-            y_max = max(y_max, int(y.max(initial=0)))
-            x_min = min(x_min, int(X.min(initial=0)))
-            y_min = min(y_min, int(y.min(initial=0)))
-        if discrete and (x_min < 0 or y_min < 0):
-            # A negative category one-hots to an all-zero row, so the
-            # observation silently vanishes from every contingency count
-            # and the resulting MI is wrong with no error anywhere.
-            raise ValueError(
-                "negative category values in discrete source "
-                f"(min feature value {x_min}, min target value {y_min}): "
-                "one-hot contingency counts drop them silently; remap "
-                "categories to 0..K-1 before fitting"
-            )
-        st = SourceStats(
-            discrete=discrete,
-            num_values=x_max + 1 if discrete else 0,
-            num_classes=y_max + 1 if discrete else 0,
-        )
+        return memo
+
+    def remember_stats(self, st: SourceStats) -> None:
+        """Memoise ``st`` as this source's :meth:`stats`, per instance and
+        by fingerprint, wherever it was taken."""
         object.__setattr__(self, "_stats", st)  # works on frozen dataclasses
+        fp = self.fingerprint()
         with _STATS_LOCK:
             _STATS_MEMO[fp] = st
             _STATS_MEMO.move_to_end(fp)
             while len(_STATS_MEMO) > _STATS_MEMO_CAP:
                 _STATS_MEMO.popitem(last=False)
-        return st
 
     def materialize(self, block_obs: int = 65536) -> Block:
         """Concatenate every block — small datasets and tests only."""
@@ -289,6 +318,19 @@ class DataSource:
         Xm.flush()
         ym.flush()
         return x_path, y_path
+
+
+def needs_category_scan(source: DataSource) -> bool:
+    """Whether ``source``'s default score is an exact MI whose category
+    counts nothing has read yet: its feature and target dtypes are both
+    known without I/O to be integral, and no :meth:`~DataSource.stats` of
+    its fingerprint is memoised.  A fit of such a source may take the
+    counts from the blocks it places instead of scanning it first."""
+    dtypes = (source.feature_dtype, source.target_dtype)
+    return (
+        all(dt is not None and is_integral(dt) for dt in dtypes)
+        and source.cached_stats() is None
+    )
 
 
 def as_source(X, y=None) -> DataSource:
@@ -331,6 +373,10 @@ class ArraySource(DataSource):
     @property
     def feature_dtype(self) -> np.dtype:
         return self.X.dtype
+
+    @property
+    def target_dtype(self) -> np.dtype:
+        return self.y.dtype
 
     def iter_blocks(self, block_obs: int) -> Iterator[Block]:
         for lo in range(0, self.num_obs, block_obs):
@@ -692,6 +738,10 @@ class ShardSource(DataSource):
     def feature_dtype(self) -> "np.dtype | None":
         return self.base.feature_dtype
 
+    @property
+    def target_dtype(self) -> "np.dtype | None":
+        return self.base.target_dtype
+
     def _fingerprint_update(self, h) -> None:
         h.update(
             f"shard|{self.base.fingerprint()}|"
@@ -770,6 +820,10 @@ class CorralSource(DataSource):
     def feature_dtype(self) -> np.dtype:
         return np.dtype(np.int8)
 
+    @property
+    def target_dtype(self) -> np.dtype:
+        return np.dtype(np.int8)
+
     def _fingerprint_update(self, h) -> None:
         # The dataset is a pure function of these parameters — no I/O.
         h.update(
@@ -835,4 +889,5 @@ __all__ = [
     "SyntheticTokenSource",
     "as_source",
     "clear_stats_memo",
+    "needs_category_scan",
 ]

@@ -384,7 +384,9 @@ class ResidentBlocks:
     their bytes; the first pass runs its placed blocks through
     :meth:`keep`; once it has ended (:attr:`complete`), each later pass
     counts from :meth:`triples` in the same block order, its target cut on
-    the device, and reads and places nothing.  The owner calls
+    the device, and reads and places nothing.  (A fit that sizes its score
+    from the kept blocks places them before it counts, and counts its
+    relevance pass from them too.)  The owner calls
     :meth:`delete` when the fit returns or raises: nothing outlives the
     fit, and the promise is withdrawn.
     """
@@ -423,10 +425,13 @@ class ResidentBlocks:
 
     def triples(self, cols, cond_classes: int | None = None, **ids):
         """Each kept block as ``(X, target, valid)`` for a pass whose
-        target is column ``cols`` (a scalar) or a stack of columns (a
-        vector), placed by :meth:`BlockPlacer.place_ids`; each cut is
-        dispatched in a ``mrmr.cut`` span with ``ids`` and the block's
-        index."""
+        target is the class (``cols`` None: the blocks as kept), column
+        ``cols`` (a scalar) or a stack of columns (a vector), placed by
+        :meth:`BlockPlacer.place_ids`; each cut is dispatched in a
+        ``mrmr.cut`` span with ``ids`` and the block's index."""
+        if cols is None:
+            yield from self.blocks
+            return
         for block, (X, y, valid) in enumerate(self.blocks):
             with tracing.span(tracing.CUT, block=block, **ids):
                 target = self.placer.cut_target(X, y, cols, cond_classes)

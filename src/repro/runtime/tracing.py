@@ -17,7 +17,9 @@ The spans, outermost first:
 
 * ``mrmr.fit``: one front-door fit of a source, planning to result;
 * ``mrmr.plan``: the front door before the engine (score and its stats
-  scan, plan, mesh);
+  scan, plan, mesh); and, where the front door left the default score to
+  the engine, the engine sizing it: the reduce over the blocks it keeps on
+  the device and its copy to the host, or the stats scan where it streams;
 * ``mrmr.pass``: one scoring pass (args ``kind``, ``batch`` and
   ``resident``, 1 where the pass counts from device-resident blocks and
   reads nothing);

@@ -65,8 +65,8 @@ import numpy as np
 
 from repro.core.criteria import Criterion, resolve_criterion
 from repro.core.mrmr import MRMRResult
-from repro.core.scores import MIScore, PearsonMIScore, ScoreFn
-from repro.core.selector import check_num_select
+from repro.core.scores import MIScore, ScoreFn
+from repro.core.selector import check_num_select, score_of_stats
 from repro.data.binning import BinnedSource
 from repro.data.sources import (
     CSVSource,
@@ -474,12 +474,7 @@ class SelectionService:
         if score is None:
             # stats() is memoised per source fingerprint, so repeat
             # submissions on the same file resolve without an I/O pass.
-            st = source.stats(block_obs)
-            score = (
-                MIScore(num_values=st.num_values, num_classes=st.num_classes)
-                if st.discrete
-                else PearsonMIScore()
-            )
+            score = score_of_stats(source.stats(block_obs))
         request = SelectionRequest(
             source=source, num_select=int(num_select), score=score,
             criterion=resolve_criterion(criterion), encoding=encoding,

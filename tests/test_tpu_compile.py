@@ -21,7 +21,7 @@ from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.scores import MIScore
-from repro.core.streaming import _cached_acc_fn
+from repro.core.streaming import _cached_acc_fn, _widen_extrema
 from repro.dist.streaming import BlockPlacer, _cut_target
 from repro.kernels import ops
 from repro.kernels.binning import bin_codes_pallas
@@ -204,3 +204,38 @@ def test_target_cut_compiles_for_v5e(
     assert out.dtype == (jnp.int8 if cond is None else jnp.int32)
     if layout == "feat":
         assert "all-reduce" in compiled.as_text()  # the owner's column
+
+
+@pytest.mark.parametrize(
+    "layout,rows,cols",
+    [("one", BLOCK, 1000), ("one", 2048, 50000), ("obs", BLOCK, 1000),
+     ("feat", 2048, 50000)],
+)
+def test_block_extrema_compile_for_v5e(
+    layout, rows, cols, topo, one_chip, no_persistent_cache
+):
+    # The reduce that sizes a default score from the resident blocks, at
+    # the benchmark's block shapes: one chip, and blocks split by rows or
+    # by columns over four, where it has to reduce across the chips.
+    if layout == "one":
+        x_sh = y_sh = e_sh = one_chip
+    else:
+        axis = "data" if layout == "obs" else "model"
+        mesh = Mesh(
+            np.asarray(topo.devices).reshape(4), (axis,),
+            axis_types=(AxisType.Auto,),
+        )
+        o, f = (axis, None) if layout == "obs" else (None, axis)
+        x_sh = NamedSharding(mesh, P(o, f))
+        y_sh = NamedSharding(mesh, P(o))
+        e_sh = NamedSharding(mesh, P())
+    scalar = jax.ShapeDtypeStruct((), jnp.int8, sharding=e_sh)
+    compiled = _widen_extrema.lower(
+        (scalar,) * 4,
+        jax.ShapeDtypeStruct((rows, cols), jnp.int8, sharding=x_sh),
+        jax.ShapeDtypeStruct((rows,), jnp.int8, sharding=y_sh),
+    ).compile()
+    outs = jax.tree.leaves(compiled.out_info)
+    assert [(o.shape, o.dtype) for o in outs] == [((), jnp.int8)] * 4
+    if layout != "one":
+        assert "all-reduce" in compiled.as_text()

@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 from repro import MRMRSelector
-from repro.data.sources import ArraySource
+from repro.data.sources import ArraySource, clear_stats_memo
 from repro.runtime import tracing
 
 ROWS, COLS, BLOCK, SELECT = 1000, 12, 256, 10
@@ -18,6 +18,13 @@ BLOCKS = -(-ROWS // BLOCK)  # 4 a pass, the last padded from 232 rows
 
 # (prefetch, readahead): synchronous, staging thread, cross-pass reader
 MODES = {"sync": (0, 0), "prefetch2": (2, 0), "readahead2": (0, 2)}
+
+
+@pytest.fixture(autouse=True)
+def first_fits():
+    """Each test's first fit is the first of its data: no memoised stats,
+    so the front door leaves the default score to the engine."""
+    clear_stats_memo()
 
 
 @pytest.fixture(scope="module")
@@ -41,8 +48,13 @@ def _fit(data, criterion="mid", mode="sync"):
 Span = collections.namedtuple("Span", "name start end args thread")
 
 
-def _traced(tmp_path, fit):
-    """Run ``fit()`` under the profiler -> (its result, the mrmr.* spans)."""
+# The host event of one dispatch of the device reduce that sizes a score
+SIZING = "PjitFunction(_widen_extrema)"
+
+
+def _traced(tmp_path, fit, also=()):
+    """Run ``fit()`` under the profiler -> (its result, the mrmr.* spans
+    and the host events named in ``also``)."""
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
     jax.profiler.start_trace(str(tmp_path), profiler_options=options)
@@ -58,7 +70,7 @@ def _traced(tmp_path, fit):
             continue
         for thread, line in enumerate(plane.lines):
             for e in line.events:
-                if e.name.startswith("mrmr."):
+                if e.name.startswith("mrmr.") or e.name in also:
                     spans.append(Span(
                         e.name, e.start_ns, e.start_ns + e.duration_ns,
                         dict(e.stats), (plane.name, thread),
@@ -91,8 +103,12 @@ def test_spans_of_a_streamed_fit(tmp_path, data, criterion, mode):
     (fit,) = by[tracing.FIT]
     fid = fit.args["fit"]
     assert all(s.args["fit"] == fid for s in spans)
-    (plan,) = by[tracing.PLAN]
-    assert _inside(plan, fit)
+    # the front door's plan, then the engine's: the front door left the
+    # default score to the engine, and a fit that streams scans for it
+    plan, scan = sorted(by[tracing.PLAN], key=lambda s: s.start)
+    assert _inside(plan, fit) and _inside(scan, fit)
+    assert plan.end <= scan.start
+    assert io["resident_stats"] == 0
 
     passes = by[tracing.PASS]
     assert len(passes) == io["passes"] == SELECT
@@ -103,6 +119,7 @@ def test_spans_of_a_streamed_fit(tmp_path, data, criterion, mode):
     assert all(p.args["resident"] == 0 for p in passes)
     assert all(_inside(p, fit) for p in passes)
     pass_of = {p.args["pass"]: p for p in passes}
+    assert scan.end <= pass_of[0].start
 
     blocks = {(p, b) for p in range(SELECT) for b in range(BLOCKS)}
     for name in (tracing.READ, tracing.STAGE, tracing.PLACE,
@@ -213,8 +230,12 @@ def test_resident_round_trip_counters(data, monkeypatch, criterion, mode):
     _resident(monkeypatch)
     io = _fit(data, criterion, mode).result_.io
     terms = 1 + (SELECT - 1) * (2 if criterion == "jmi" else 1)
-    assert io["resident_passes"] == SELECT - 1
-    assert io["host_syncs"] == terms + SELECT
+    # every pass counts from the resident blocks, the relevance pass too:
+    # the score was sized from them, with one more copy to the host
+    assert io["resident_passes"] == SELECT
+    assert io["resident_stats"] == 1
+    assert io["host_syncs"] == terms + SELECT + 1
+    assert (io["host_syncs"], criterion) in {(21, "mid"), (30, "jmi")}
     # the passes after the first cut their targets on the device
     assert io["h2d_bytes"] == _resident_h2d(BLOCKS, BLOCK, COLS, terms, SELECT)
     # the benchmark's geometries at L = 10: corral_tall_1m (16 blocks of
@@ -228,14 +249,28 @@ def test_resident_round_trip_counters(data, monkeypatch, criterion, mode):
 @pytest.mark.parametrize("criterion", ["mid", "jmi"])
 def test_spans_of_a_resident_fit(tmp_path, data, monkeypatch, criterion):
     _resident(monkeypatch)
-    sel, spans = _traced(tmp_path, lambda: _fit(data, criterion))
+    sel, spans = _traced(
+        tmp_path, lambda: _fit(data, criterion), also=(SIZING,)
+    )
     by = _by_name(spans)
     passes = {p.args["pass"]: p for p in by[tracing.PASS]}
+    # every pass counts from the resident blocks, the relevance pass too
     assert {p: s.args["resident"] for p, s in passes.items()} == {
-        p: int(p > 0) for p in range(SELECT)
+        p: 1 for p in range(SELECT)
     }
-    # only the first pass reads, stages and places; every later pass
-    # dispatches the device cut of its target; every pass accumulates
+    # the front door's plan, then the engine's, which sizes the score on
+    # the device from the placed blocks before the relevance pass counts
+    (fit,) = by[tracing.FIT]
+    plan, sizing = sorted(by[tracing.PLAN], key=lambda s: s.start)
+    assert _inside(plan, fit) and _inside(sizing, fit)
+    assert plan.end <= sizing.start and sizing.end <= passes[0].start
+    assert by[SIZING] and all(_inside(s, sizing) for s in by[SIZING])
+    assert all(
+        s.end <= sizing.start for s in by[tracing.STAGE] + by[tracing.PLACE]
+    )
+    # only the first pass reads, stages and places, ahead of any count;
+    # every later pass dispatches the device cut of its target; every
+    # pass accumulates
     first = {(0, b) for b in range(BLOCKS)}
     later = {(p, b) for p in range(1, SELECT) for b in range(BLOCKS)}
     for name, want in [
@@ -245,9 +280,9 @@ def test_spans_of_a_resident_fit(tmp_path, data, monkeypatch, criterion):
     ]:
         got = [(s.args["pass"], s.args["block"]) for s in by[name]]
         assert sorted(got) == sorted(want), name
-    for s in by[tracing.STAGE] + by[tracing.CUT] + by[tracing.ACCUMULATE]:
+    for s in by[tracing.CUT] + by[tracing.ACCUMULATE]:
         assert _inside(s, passes[s.args["pass"]])
-    assert sel.result_.io["resident_passes"] == SELECT - 1
+    assert sel.result_.io["resident_passes"] == SELECT
 
 
 @pytest.mark.parametrize("criterion", ["mid", "jmi"])
