@@ -23,6 +23,8 @@ from repro import CustomScore, MRMRSelector, PearsonMIScore
 from repro.core.scores import cor2mi
 from repro.data.synthetic import continuous_wide_dataset
 
+N_OBS, N_FEAT, K = 2_000, 4_096, 8
+
 
 # --- 1. paper Listing 8, literally -----------------------------------------
 def listing8_get_result(v, cls, selected, n_selected):
@@ -65,21 +67,23 @@ def anova_f_get_result(v, cls, selected, n_selected):
 
 
 def main():
-    X, y = continuous_wide_dataset(2_000, 4_096, seed=0)
+    X, y = continuous_wide_dataset(N_OBS, N_FEAT, seed=0)
     X = jnp.asarray(X)  # conventional orientation (obs × features)
 
+    selected = {}
     for name, score in [
         ("built-in PearsonMI", PearsonMIScore()),
         ("Listing 8 (custom)", CustomScore(get_result=listing8_get_result)),
         ("ANOVA-F (custom)", CustomScore(get_result=anova_f_get_result)),
     ]:
-        fs = MRMRSelector(num_select=8, score=score).fit(X, y)
-        sel = list(fs.selected_)
+        fs = MRMRSelector(num_select=K, score=score).fit(X, y)
+        sel = selected[name] = [int(k) for k in fs.selected_]
         print(f"{name:>20s}: selected {sel} "
               f"(encoding={fs.plan_.encoding})")
         print(f"{'':>20s}  signal cols (0-7) recovered: "
               f"{len(set(sel) & set(range(8)))}/8, "
               f"redundant shadow col 8 picked: {8 in sel}")
+    return selected
 
 
 if __name__ == "__main__":

@@ -287,17 +287,23 @@ Layers
   engine registry; pluggable feature-score functions AND pluggable
   selection criteria (``repro.core.criteria``); incremental fold
   optimisation.
-* ``repro.dist``    — the distribution substrate: named meshes, logical
-  sharding rules, multi-host map-reduce (``repro.dist.multihost``),
-  pipeline parallelism.
-* ``repro.kernels`` — Pallas TPU kernels for the scoring hot spots.
-* ``repro.models``  — architecture zoo (dense / MoE / SSM / hybrid /
-  enc-dec / VLM backbones) used as workloads for the substrate.
+* ``repro.data``    — data sources (arrays, ``.npy``, CSV, Parquet,
+  CorrAL), the spill cache and quantile binning.
+* ``repro.dist``    — the distribution substrate: named meshes, block
+  placement and device-resident blocks for streamed fits
+  (``repro.dist.streaming``), multi-host map-reduce
+  (``repro.dist.multihost``).
+* ``repro.kernels`` — Pallas TPU kernels for the scoring hot spots
+  (contingency counts, MI score, binning, Pearson), their dispatch and
+  their jnp oracle.
+* ``repro.runtime`` — compile cache, profiler spans, retry, checkpoints.
+* ``repro.interop`` — the scikit-learn adapter (``MRMRTransformer``).
 * ``repro.serve``   — selection-as-a-service: job manager, coalescing
-  work queue, content-addressed result cache (plus the LM serving demo).
-* ``repro.launch``  — production mesh, multi-pod dry-run, CLIs
-  (``python -m repro.launch.select`` runs selection end-to-end,
-  ``python -m repro.launch.serve_select`` drives the service).
+  work queue, content-addressed result cache.
+* ``repro.launch``  — CLIs (``python -m repro.launch.select`` runs
+  selection end-to-end, ``python -m repro.launch.select_multihost`` runs
+  it across processes, ``python -m repro.launch.serve_select`` drives the
+  service).
 """
 
 from repro.core import (  # noqa: F401

@@ -373,7 +373,6 @@ def _conventional_body(
     incremental: bool,
     block: int,
     onehot_dtype=jnp.bfloat16,
-    static_inner: bool = False,
 ):
     n = X_loc.shape[1]
     v, c = score.num_values, score.num_classes
@@ -417,22 +416,11 @@ def _conventional_body(
         elif incremental:
             g = crit.objective(rel, st["crit"], l)
         else:
-            # static_inner trades the data-dependent trip count (paper: l-1
-            # passes at step l) for a fixed L-pass masked loop, so the
-            # dry-run HLO carries the recompute cost explicitly.
             def inner(j, cs):
                 xj = jnp.take(X_loc, st["selected"][j], axis=1)
-                folded = crit.update(cs, pair_terms(xj), j)
-                if static_inner:
-                    # Fold unconditionally (the dry-run carries the cost),
-                    # keep the state only for the real j < l iterations.
-                    return jax.tree.map(
-                        lambda a, b: jnp.where(j < l, b, a), cs, folded
-                    )
-                return folded
+                return crit.update(cs, pair_terms(xj), j)
 
-            hi = num_select if static_inner else l
-            cs = lax.fori_loop(0, hi, inner, crit.init_state(n))
+            cs = lax.fori_loop(0, l, inner, crit.init_state(n))
             g = crit.objective(rel, cs, l)
         g = jnp.where(st["mask"], _NEG_INF, g)
         k = jnp.argmax(g).astype(jnp.int32)
@@ -486,14 +474,13 @@ def make_conventional_fn(
     incremental: bool = True,
     block: int = 64,
     onehot_dtype=jnp.bfloat16,
-    static_inner: bool = False,
     criterion: Criterion | str = "mid",
 ):
     """Jitted (X, y) -> (selected, gains, relevance) for the conventional
     encoding.
 
-    Exposed separately so benchmarks can ``.lower().compile()`` the job and
-    run the same HLO collective analysis as the LM dry-run cells.
+    Exposed separately so :func:`repro.core.selector.build_engine_fn` can
+    build it once per geometry and keep it warm across fits.
     """
     if not isinstance(score, MIScore):
         raise ValueError(
@@ -507,7 +494,6 @@ def make_conventional_fn(
         incremental=incremental,
         block=block,
         onehot_dtype=onehot_dtype,
-        static_inner=static_inner,
     )
     if mesh is None:
         return jax.jit(functools.partial(_conventional_body, obs_axes=(), **kwargs))

@@ -103,7 +103,6 @@ class SelectionPlan:
     incremental: bool = True          # running criterion fold vs recompute
     score: ScoreFn | None = None      # score spec (None = auto from data)
     onehot_dtype: str = "bfloat16"    # contingency one-hot storage dtype
-    static_inner: bool = False        # fixed-trip recompute loop (dry-run)
     block_obs: int = 65536            # streaming: EFFECTIVE observations per
                                       # block (rounded up to the obs extent)
     prefetch: int = 2                 # streaming: blocks placed ahead of
@@ -311,8 +310,8 @@ def build_engine_fn(
     Native layouts: conventional/grid take (obs, feat) [padded to mesh
     divisibility]; reference/alternative take feature-major (feat, obs).
     The relevance output covers the engine's (padded) feature extent.
-    Benchmarks use this directly to ``.lower().compile()`` the exact job
-    the selector would run.
+    ``.lower().compile()`` on it compiles ahead of time the exact job the
+    selector would run.
     """
     enc, score = plan.encoding, plan.score
     crit = resolve_criterion(plan.criterion)
@@ -331,8 +330,7 @@ def build_engine_fn(
         return mrmr_mod.make_conventional_fn(
             num_select, score, mesh=mesh, obs_axes=plan.obs_axes,
             incremental=plan.incremental, block=plan.block,
-            onehot_dtype=oh_dt, static_inner=plan.static_inner,
-            criterion=crit,
+            onehot_dtype=oh_dt, criterion=crit,
         )
     if enc == "alternative":
         return mrmr_mod.make_alternative_fn(
@@ -365,7 +363,7 @@ def _engine_fn_key(plan: SelectionPlan, mesh, num_select: int, n_features: int):
         "engine_fn", plan.encoding, plan.score,
         resolve_criterion(plan.criterion), num_select, n_features, mesh,
         plan.block, plan.incremental, plan.obs_axes, plan.feat_axes,
-        plan.onehot_dtype, plan.static_inner,
+        plan.onehot_dtype,
     )
 
 

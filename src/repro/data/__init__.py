@@ -4,8 +4,7 @@ from repro.data.binning import (  # noqa: F401
     QuantileSketch,
     fit_binned,
 )
-from repro.data.synthetic import corral_dataset, lm_token_batches  # noqa: F401
-from repro.data.pipeline import ShardedDataPipeline  # noqa: F401
+from repro.data.synthetic import corral_dataset  # noqa: F401
 from repro.data.sources import (  # noqa: F401
     ArraySource,
     CSVSource,
@@ -13,6 +12,5 @@ from repro.data.sources import (  # noqa: F401
     DataSource,
     NpySource,
     SourceStats,
-    SyntheticTokenSource,
     as_source,
 )

@@ -21,12 +21,6 @@ size, never by ``num_obs``.
 
 This module is deliberately numpy-only: importing it never initialises a
 jax backend, so launchers can still set ``XLA_FLAGS`` after import.
-
-The LM side of the repo speaks the same block language through
-:class:`SyntheticTokenSource` — a *step-indexed* source (an infinite
-stream pure in ``(seed, step)``) that ``repro.data.pipeline`` places onto
-a mesh; finite selection sources and infinite token sources are the two
-faces of one host-blocks protocol.
 """
 
 from __future__ import annotations
@@ -852,30 +846,6 @@ class CorralSource(DataSource):
         )
 
 
-# ---------------------------------------------------------------------------
-# step-indexed token sources (the LM-pipeline face of the protocol)
-# ---------------------------------------------------------------------------
-
-@dataclasses.dataclass(frozen=True)
-class SyntheticTokenSource:
-    """Infinite step-indexed token stream, pure in ``(seed, step)``.
-
-    ``block(step, lo, hi)`` returns rows [lo, hi) of the global batch at
-    ``step`` — the restart-replay property ``ShardedDataPipeline`` builds
-    its fault tolerance on (same Zipf-ish marginal as
-    ``synthetic.lm_token_batches``)."""
-
-    global_batch: int
-    seq_len: int
-    vocab: int
-    seed: int = 0
-
-    def block(self, step: int, lo: int, hi: int) -> np.ndarray:
-        rng = np.random.default_rng((self.seed, step))
-        u = rng.random((self.global_batch, self.seq_len + 1))[lo:hi]
-        return (u * u * self.vocab).astype(np.int32)
-
-
 __all__ = [
     "ArraySource",
     "ArrowSource",
@@ -886,7 +856,6 @@ __all__ = [
     "ParquetSource",
     "ShardSource",
     "SourceStats",
-    "SyntheticTokenSource",
     "as_source",
     "clear_stats_memo",
     "needs_category_scan",

@@ -1,4 +1,4 @@
-"""Synthetic datasets — the paper's CorrAL-style generator (Eq. 3) + LM tokens.
+"""Synthetic datasets — the paper's CorrAL-style generator (Eq. 3).
 
 The paper evaluates on binary artificial datasets where the class depends on
 8 features:
@@ -7,14 +7,11 @@ The paper evaluates on binary artificial datasets where the class depends on
 
 with all remaining features irrelevant noise.  We reproduce that generator
 (deterministically, chunked so millions of rows stream without a host-memory
-spike) and add: a partially-correlated column (as in CorrAL), continuous
-variants for the alternative-encoding/Pearson path, and LM token batches for
-the architecture workloads.
+spike) and add a partially-correlated column (as in CorrAL) and continuous
+variants for the alternative-encoding/Pearson path.
 """
 
 from __future__ import annotations
-
-import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -122,29 +119,3 @@ def continuous_wide_dataset(
         )
     return X, y.astype(jnp.int32)
 
-
-# ---------------------------------------------------------------------------
-# LM token stream for the architecture workloads
-# ---------------------------------------------------------------------------
-
-@dataclasses.dataclass(frozen=True)
-class LMBatch:
-    tokens: Array  # (B, S) int32
-    targets: Array  # (B, S) int32 (next-token shifted)
-    mask: Array  # (B, S) float32 loss mask
-
-
-def lm_token_batches(
-    key: Array, batch: int, seq_len: int, vocab: int, num_batches: int = 1
-):
-    """Deterministic synthetic token batches (Zipf-ish marginal)."""
-    for i in range(num_batches):
-        k = jax.random.fold_in(key, i)
-        # Zipf-like: square a uniform to skew mass toward low token ids.
-        u = jax.random.uniform(k, (batch, seq_len + 1))
-        tokens = (u * u * vocab).astype(jnp.int32)
-        yield LMBatch(
-            tokens=tokens[:, :-1],
-            targets=tokens[:, 1:],
-            mask=jnp.ones((batch, seq_len), jnp.float32),
-        )

@@ -1,7 +1,7 @@
 """Named device meshes.
 
 ``make_mesh`` is the one mesh constructor in the repo: everything from the
-2-device CPU debug mesh to the 512-chip dry-run pod goes through it, so
+2-device CPU debug mesh to a multi-chip TPU host goes through it, so
 device selection and axis naming cannot drift between launchers, tests and
 benchmarks.
 """

@@ -16,7 +16,6 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.binning import bin_codes_pallas
@@ -113,25 +112,3 @@ def mi_tables(
     counts = contingency_tables(X, y, num_values, num_classes, use_pallas)
     return mi_scores(counts, use_pallas)
 
-
-@functools.partial(
-    jax.jit, static_argnames=("causal", "block_q", "block_kv", "use_pallas")
-)
-def flash_attention(
-    q: Array, k: Array, v: Array, *, causal: bool = True,
-    block_q: int = 512, block_kv: int = 512, use_pallas="auto",
-) -> Array:
-    """(B,S,H,D), (B,T,KV,D) -> (B,S,H,D) GQA flash attention.
-
-    The TPU path for every attention cell in the §Roofline table (keeps the
-    S^2 score/prob intermediates in VMEM); the jnp oracle runs on CPU.
-    """
-    from repro.kernels.flash_attention import flash_attention_pallas
-
-    run, interp = _decide(use_pallas)
-    if run:
-        return flash_attention_pallas(
-            q, k, v, causal=causal, block_q=block_q, block_kv=block_kv,
-            interpret=interp,
-        )
-    return ref.flash_attention(q, k, v, causal=causal)

@@ -68,17 +68,3 @@ def cor2mi(corr: Array) -> Array:
     """Listing-8 Gaussian MI approximation."""
     return _scores.cor2mi(corr)
 
-
-def flash_attention(q: Array, k: Array, v: Array, *, causal: bool) -> Array:
-    """(B,S,H,D) x (B,T,KV,D) -> (B,S,H,D) GQA softmax attention (f32)."""
-    b, s, h, d = q.shape
-    kvh = k.shape[2]
-    qg = q.reshape(b, s, kvh, h // kvh, d).astype(jnp.float32) * (d ** -0.5)
-    sc = jnp.einsum("bskgd,btkd->bkgst", qg, k.astype(jnp.float32))
-    if causal:
-        t = k.shape[1]
-        mask = jnp.tril(jnp.ones((s, t), jnp.bool_), k=t - s)
-        sc = jnp.where(mask[None, None, None], sc, -1e30)
-    p = jax.nn.softmax(sc, axis=-1)
-    out = jnp.einsum("bkgst,btkd->bskgd", p, v.astype(jnp.float32))
-    return out.reshape(b, s, h, d).astype(q.dtype)

@@ -1,2 +1,1 @@
 from repro.runtime.checkpoint import CheckpointManager  # noqa: F401
-from repro.runtime.resilience import StepWatchdog, run_with_restarts  # noqa: F401

@@ -1,31 +1,13 @@
-"""Fault-tolerance runtime: watchdog, crash-restart driver, elastic re-shard.
+"""Call-level retry for transient failures.
 
-On a real multi-pod deployment, failures surface as (a) hung collectives
-(node loss -> step never completes), (b) process crashes, (c) degraded
-stragglers.  The mitigations here are the host-side halves that are
-testable on CPU; the launch scripts (launch/run_*.sh) pair them with the
-TPU-side flags (--xla_tpu_enable_flash_... timeouts, preemption signal
-handling).
-
-* ``StepWatchdog``  — per-step heartbeat; a step exceeding ``timeout_s``
-  triggers ``on_stall`` (default: log loudly).  Catches hung collectives
-  and stragglers: the driver can checkpoint-skip or abort for the restart
-  wrapper to take over.
-* ``run_with_restarts`` — crash-restart loop: on exception, restore the
-  latest checkpoint and resume (bounded retries).  Paired with the
-  deterministic step-indexed data pipeline, restarts are replay-exact.
-* ``retry_with_backoff`` — call-level retry with exponential backoff for
-  transient failures (flaky I/O, a preempted worker); the selection
-  service wraps each engine run in it so one wobble never fails a job.
-* ``elastic_restore`` — restore a checkpoint under a DIFFERENT mesh: the
-  checkpoint layout is mesh-agnostic (host-side full arrays), so scaling
-  from N to M pods is a restore with new shardings.
+``retry_with_backoff`` retries a call with exponential backoff when it
+raises a retryable error (flaky I/O, a preempted worker); the selection
+service wraps each engine run in it so one wobble never fails a job.
 """
 
 from __future__ import annotations
 
 import logging
-import threading
 import time
 from typing import Callable
 
@@ -79,109 +61,3 @@ def retry_with_backoff(
             sleep(delay)
             attempt += 1
 
-
-class StepWatchdog:
-    """Heartbeat monitor: call ``beat(step)`` once per train step."""
-
-    def __init__(
-        self,
-        timeout_s: float = 300.0,
-        on_stall: Callable[[int, float], None] | None = None,
-        poll_s: float = 1.0,
-    ):
-        self.timeout_s = timeout_s
-        self.on_stall = on_stall or self._default_stall
-        self.poll_s = poll_s
-        self._last_beat = time.monotonic()
-        self._last_step = -1
-        self._stalled_steps: list[int] = []
-        self._stop = threading.Event()
-        self._thread: threading.Thread | None = None
-
-    def _default_stall(self, step: int, elapsed: float) -> None:
-        logger.error(
-            "step %d stalled for %.1fs (straggler or hung collective)",
-            step, elapsed,
-        )
-
-    def beat(self, step: int) -> None:
-        self._last_beat = time.monotonic()
-        self._last_step = step
-
-    @property
-    def stalled_steps(self) -> list[int]:
-        return list(self._stalled_steps)
-
-    def _run(self) -> None:
-        while not self._stop.wait(self.poll_s):
-            elapsed = time.monotonic() - self._last_beat
-            if elapsed > self.timeout_s:
-                self._stalled_steps.append(self._last_step)
-                self.on_stall(self._last_step, elapsed)
-                self._last_beat = time.monotonic()  # rate-limit alarms
-
-    def __enter__(self) -> "StepWatchdog":
-        self._thread = threading.Thread(target=self._run, daemon=True)
-        self._thread.start()
-        return self
-
-    def __exit__(self, *exc) -> None:
-        self._stop.set()
-        if self._thread is not None:
-            self._thread.join(timeout=5)
-
-
-def run_with_restarts(
-    make_state: Callable[[], object],
-    run_from: Callable[[object], object],
-    *,
-    ckpt,
-    state_like_fn: Callable[[], object],
-    shardings=None,
-    max_restarts: int = 3,
-):
-    """Crash-restart driver.
-
-    ``make_state()`` builds a fresh state (cold start); ``run_from(state)``
-    trains until done (raising on failure); ``ckpt`` is a CheckpointManager.
-    On failure, restores the latest checkpoint (or cold-starts when none)
-    and re-enters, up to ``max_restarts`` times.
-    """
-    attempts = 0
-    while True:
-        try:
-            step = ckpt.latest_step()
-            if step is None:
-                state = make_state()
-                logger.info("cold start")
-            else:
-                state = ckpt.restore(step, state_like_fn(), shardings)
-                logger.info("restored checkpoint step %d", step)
-            return run_from(state)
-        except KeyboardInterrupt:
-            raise
-        except Exception as e:  # noqa: BLE001 — restart on any failure
-            attempts += 1
-            logger.exception("run failed (attempt %d): %s", attempts, e)
-            if attempts > max_restarts:
-                raise
-            time.sleep(min(2.0**attempts, 30.0))
-
-
-def elastic_restore(ckpt, step: int, bundle, opt_cfg, new_mesh):
-    """Restore a checkpoint onto a different mesh (elastic scale up/down)."""
-    import dataclasses
-
-    from repro.models.model import build_model
-    from repro.train.train_step import make_train_state_specs, train_state_shapes
-    import jax
-    from jax.sharding import NamedSharding
-
-    new_bundle = build_model(bundle.cfg, new_mesh)
-    like = train_state_shapes(new_bundle, opt_cfg)
-    specs = make_train_state_specs(new_bundle)
-    shardings = jax.tree.map(
-        lambda s: NamedSharding(new_mesh, s), specs,
-        is_leaf=lambda x: hasattr(x, "_parsed_pspec") or x.__class__.__name__ == "PartitionSpec",
-    )
-    return new_bundle, ckpt.restore(step, like, shardings)

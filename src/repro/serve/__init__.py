@@ -1,4 +1,3 @@
-from repro.serve.engine import ServeEngine, Request  # noqa: F401
 from repro.serve.selection import (  # noqa: F401
     Backpressure,
     JobCancelled,

@@ -710,11 +710,13 @@ class TestSummedFoldCriteria:
                                        rtol=5e-3, atol=1e-5)
 
     @pytest.mark.parametrize("criterion", ["mifs", "cife", "icap"])
-    def test_streaming_matches_reference(self, corral, criterion):
+    @pytest.mark.parametrize("block_obs", [128, 999, 4096])
+    def test_streaming_matches_reference(self, corral, criterion,
+                                         block_obs):
         X, y = corral
         ref = fit(X, y, "reference", criterion=criterion)
         got = MRMRSelector(num_select=5, criterion=criterion,
-                           block_obs=999).fit(ArraySource(X, y))
+                           block_obs=block_obs).fit(ArraySource(X, y))
         assert got.plan_.encoding == "streaming"
         np.testing.assert_array_equal(got.selected_, ref.selected_)
         np.testing.assert_allclose(got.gains_, ref.gains_, rtol=1e-4,

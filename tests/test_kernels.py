@@ -5,7 +5,6 @@ in Python), which validates the BlockSpec tiling, accumulation-across-grid
 logic and padding behaviour against ``repro.kernels.ref``.
 """
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -14,7 +13,6 @@ from repro.kernels import ops, ref
 from repro.kernels.binning import bin_codes_pallas
 from repro.kernels.contingency import contingency_tables_pallas
 from repro.kernels.mi_score import mi_scores_pallas
-from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.pearson import pearson_corr_pallas
 
 
@@ -215,73 +213,3 @@ class TestOpsDispatch:
 
         want = mi_from_counts(ref.contingency_tables(X, y, 2, 2))
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
-
-
-class TestFlashAttentionKernel:
-    @pytest.mark.parametrize(
-        "b,s,h,kv,d,causal",
-        [
-            (2, 256, 8, 4, 64, True),
-            (1, 128, 4, 4, 32, False),   # MHA (kv == h)
-            (2, 512, 8, 2, 64, True),    # GQA group 4
-            (1, 256, 8, 1, 128, True),   # MQA
-        ],
-    )
-    def test_matches_oracle(self, b, s, h, kv, d, causal):
-        key = jax.random.PRNGKey(0)
-        q = jax.random.normal(key, (b, s, h, d), jnp.float32)
-        k = jax.random.normal(jax.random.fold_in(key, 1), (b, s, kv, d))
-        v = jax.random.normal(jax.random.fold_in(key, 2), (b, s, kv, d))
-        out = flash_attention_pallas(
-            q, k, v, causal=causal, block_q=128, block_kv=128, interpret=True
-        )
-        want = ref.flash_attention(q, k, v, causal=causal)
-        np.testing.assert_allclose(
-            np.asarray(out), np.asarray(want), rtol=2e-5, atol=2e-5
-        )
-
-    @pytest.mark.parametrize("bq,bkv", [(64, 128), (128, 64), (256, 256)])
-    def test_block_sweep(self, bq, bkv):
-        key = jax.random.PRNGKey(3)
-        q = jax.random.normal(key, (1, 256, 4, 64))
-        k = jax.random.normal(jax.random.fold_in(key, 1), (1, 256, 2, 64))
-        v = jax.random.normal(jax.random.fold_in(key, 2), (1, 256, 2, 64))
-        out = flash_attention_pallas(
-            q, k, v, causal=True, block_q=bq, block_kv=bkv, interpret=True
-        )
-        want = ref.flash_attention(q, k, v, causal=True)
-        np.testing.assert_allclose(
-            np.asarray(out), np.asarray(want), rtol=2e-5, atol=2e-5
-        )
-
-    def test_bf16(self):
-        key = jax.random.PRNGKey(4)
-        q = jax.random.normal(key, (2, 128, 4, 64), jnp.bfloat16)
-        k = jax.random.normal(jax.random.fold_in(key, 1), (2, 128, 4, 64),
-                              jnp.bfloat16)
-        v = jax.random.normal(jax.random.fold_in(key, 2), (2, 128, 4, 64),
-                              jnp.bfloat16)
-        out = flash_attention_pallas(
-            q, k, v, causal=True, block_q=64, block_kv=64, interpret=True
-        )
-        want = ref.flash_attention(q, k, v, causal=True)
-        np.testing.assert_allclose(
-            np.asarray(out, np.float32), np.asarray(want, np.float32),
-            rtol=3e-2, atol=3e-2,
-        )
-
-    def test_matches_model_blockwise_path(self):
-        from repro.models.attention import blockwise_attention
-
-        key = jax.random.PRNGKey(5)
-        q = jax.random.normal(key, (1, 512, 8, 64))
-        k = jax.random.normal(jax.random.fold_in(key, 1), (1, 512, 4, 64))
-        v = jax.random.normal(jax.random.fold_in(key, 2), (1, 512, 4, 64))
-        out = flash_attention_pallas(
-            q, k, v, causal=True, block_q=128, block_kv=128, interpret=True
-        )
-        want = blockwise_attention(q, k, v, causal=True, block_q=128,
-                                   block_kv=128)
-        np.testing.assert_allclose(
-            np.asarray(out), np.asarray(want), rtol=3e-5, atol=3e-5
-        )

@@ -8,6 +8,7 @@ import pytest
 from repro.core import (
     MIScore,
     PearsonMIScore,
+    make_conventional_fn,
     mrmr_alternative,
     mrmr_conventional,
     mrmr_reference,
@@ -110,6 +111,19 @@ class TestEncodingAgreement:
         ref = mrmr_reference(jnp.asarray(X.T), jnp.asarray(y), 4, score)
         cus = mrmr_alternative(jnp.asarray(X.T), jnp.asarray(y), 4, custom)
         np.testing.assert_array_equal(ref.selected, cus.selected)
+
+    def test_bf16_onehot_counts_exact(self):
+        # 0/1 one-hot entries are exact in bfloat16: the conventional
+        # engine's default one-hot dtype counts what float32 counts.
+        X, y = corral_dataset(4096, 16, seed=1)
+        X, y = jnp.asarray(X, jnp.int32), jnp.asarray(y)
+        score = MIScore(num_values=2, num_classes=2)
+        bf = make_conventional_fn(6, score, onehot_dtype=jnp.bfloat16)(X, y)
+        f32 = make_conventional_fn(6, score, onehot_dtype=jnp.float32)(X, y)
+        np.testing.assert_array_equal(np.asarray(bf[0]), np.asarray(f32[0]))
+        np.testing.assert_allclose(
+            np.asarray(bf[1]), np.asarray(f32[1]), rtol=1e-6, atol=1e-7
+        )
 
 
 class TestCorral:

@@ -7,7 +7,11 @@ import pytest
 hypothesis = pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st, assume, HealthCheck  # noqa: E402
 
-from repro.core import MIScore, mrmr_reference, mi_from_counts
+from repro import MRMRSelector  # noqa: E402
+from repro.core import MIScore, mrmr_reference, mi_from_counts  # noqa: E402
+from repro.core.criteria import resolve_criterion  # noqa: E402
+from repro.core.scores import cmi_from_counts  # noqa: E402
+from repro.data.sources import ArraySource  # noqa: E402
 
 _SETTINGS = dict(
     max_examples=10,
@@ -151,3 +155,74 @@ def test_mi_data_processing(seed, m):
     hx = float(entropy_from_counts(counts.sum(axis=1)))
     hy = float(entropy_from_counts(counts.sum(axis=0)))
     assert mi <= min(hx, hy) + 1e-5
+
+
+
+_CRITERIA = ("mid", "miq", "maxrel", "jmi", "cmim", "mifs", "cife", "icap")
+
+
+@st.composite
+def streamed_datasets(draw):
+    """(X (obs, feat), y, V, C, block_obs): V 2-4 categories, C 2-3
+    classes, and a block size that leaves the last block short."""
+    v = draw(st.integers(2, 4))
+    c = draw(st.integers(2, 3))
+    m = draw(st.sampled_from([40, 97]))
+    block_obs = draw(st.sampled_from([7, 30]))  # divides neither m
+    rng = np.random.default_rng(draw(st.integers(0, 2**31 - 1)))
+    X = rng.integers(0, v, (m, 6)).astype(np.int8)
+    y = rng.integers(0, c, m).astype(np.int8)
+    return X, y, v, c, block_obs
+
+
+def _min_gap(X, y, L, v, c, criterion):
+    """Smallest top-2 objective gap along the greedy trajectory, from
+    exact counts folded by the criterion's own hooks: a near-zero gap is
+    a score tie, which float32 summation order may break either way."""
+    crit = resolve_criterion(criterion)
+    n = X.shape[1]
+
+    def counts(b, vb):  # (n, v, vb) tables of every column against b
+        t = np.zeros((n, v, vb))
+        for k in range(n):
+            np.add.at(t[k], (X[:, k], b), 1)
+        return t
+
+    rel = mi_from_counts(jnp.asarray(counts(y, c)))
+    state, selected, gap = crit.init_state(n), [], np.inf
+    for l in range(L):
+        g = np.asarray(crit.objective(rel, state, l), np.float64)
+        g[selected] = -np.inf
+        top = np.sort(g)[::-1]
+        gap = min(gap, top[0] - top[1]) if l < n - 1 else gap
+        k = int(np.argmax(g))
+        selected.append(k)
+        if crit.needs_redundancy:
+            fused = X[:, k].astype(np.int64) * c + y
+            cnt = counts(fused, v * c).reshape(n, v, v, c)
+            terms = dict(marginal=mi_from_counts(jnp.asarray(cnt.sum(-1))),
+                         conditional=cmi_from_counts(jnp.asarray(cnt)))
+            if not crit.needs_conditional_redundancy:
+                terms = terms["marginal"]
+            state = crit.update(state, terms, l)
+    return gap
+
+
+@pytest.mark.parametrize("criterion", _CRITERIA)
+@given(streamed_datasets())
+@settings(**_SETTINGS)
+def test_streaming_matches_reference(criterion, data):
+    """A streamed fit over ragged blocks selects what the in-memory
+    reference selects, for every registered criterion (absent score
+    ties)."""
+    X, y, v, c, block_obs = data
+    L = 4
+    assume(_min_gap(X, y, L, v, c, criterion) > 1e-4)
+    score = MIScore(v, c)
+    ref = mrmr_reference(jnp.asarray(X.T.astype(np.int32)),
+                         jnp.asarray(y.astype(np.int32)), L, score,
+                         criterion=criterion)
+    got = MRMRSelector(num_select=L, score=score, criterion=criterion,
+                       block_obs=block_obs).fit(ArraySource(X, y))
+    assert got.plan_.encoding == "streaming"
+    np.testing.assert_array_equal(got.selected_, np.asarray(ref.selected))

@@ -7,6 +7,7 @@ import jax
 import pytest
 
 from repro import CustomScore, MIScore, MRMRSelector, PearsonMIScore
+from repro.core.criteria import resolve_criterion
 from repro.core.streaming import mrmr_streaming
 from repro.data.binning import BinnedSource
 from repro.data.sources import (
@@ -15,7 +16,6 @@ from repro.data.sources import (
     CorralSource,
     DataSource,
     NpySource,
-    SyntheticTokenSource,
     as_source,
 )
 from repro.dist import BlockPlacer, PrefetchPlacer, factor_mesh, make_mesh
@@ -116,12 +116,6 @@ class TestSources:
             as_source(src, y)
         with pytest.raises(ValueError, match="target"):
             as_source(X)
-
-    def test_token_source_is_step_pure(self):
-        src = SyntheticTokenSource(32, 8, 100, seed=1)
-        full = src.block(3, 0, 32)
-        assert full.shape == (32, 9) and full.dtype == np.int32
-        np.testing.assert_array_equal(src.block(3, 10, 20), full[10:20])
 
 
 class TestStreamingEquivalence:
@@ -893,7 +887,9 @@ class TestResidentBlocks:
         assert resident_budget(jax.devices()[:1]) is None  # the CPU backend
 
     @pytest.mark.parametrize("q", [1, 4])
-    @pytest.mark.parametrize("criterion", ["mid", "miq", "jmi", "cmim"])
+    @pytest.mark.parametrize(
+        "criterion", ["mid", "miq", "jmi", "cmim", "mifs", "cife", "icap"]
+    )
     def test_bitwise_the_streamed_fit(self, data, monkeypatch, criterion, q):
         streamed = self.fit(data, criterion=criterion, batch_candidates=q)
         self.budget(monkeypatch, 1 << 40)
@@ -911,7 +907,8 @@ class TestResidentBlocks:
         # one pass of placed triples (int8 X, int8 y, bool validity),
         # the relevance vector and each folded redundancy term, and the
         # int32 ids of the q columns each later pass cuts
-        terms = 1 + (self.SELECT - 1) * (2 if criterion in ("jmi", "cmim") else 1)
+        cond = resolve_criterion(criterion).needs_conditional_redundancy
+        terms = 1 + (self.SELECT - 1) * (2 if cond else 1)
         vectors = 4 * self.COLS * terms
         ids = 4 * q * io["resident_passes"]
         assert io["h2d_bytes"] == (

@@ -161,6 +161,59 @@ def test_sharded_accumulate_compiles_for_2x2(
 
 
 @pytest.mark.parametrize(
+    "layout,rows,cols",
+    [("one", BLOCK, 1000), ("obs", BLOCK, 1000), ("feat", 2048, 50000)],
+)
+def test_batched_accumulate_compiles_for_v5e(
+    layout, rows, cols, topo, one_chip, no_persistent_cache, monkeypatch
+):
+    # A redundancy pass that counts q = 4 candidate columns in one sweep
+    # (batch_candidates=4): the accumulate vmapped over (q, B) targets with
+    # the block shared, at the benchmark's block shapes on one chip and
+    # split by rows or by columns over four.
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    q = 4
+    if layout == "one":
+        mesh, obs, feat = None, (), ()
+    else:
+        axis = "data" if layout == "obs" else "model"
+        mesh = Mesh(
+            np.asarray(topo.devices).reshape(4), (axis,),
+            axis_types=(AxisType.Auto,),
+        )
+        obs, feat = ((axis,), ()) if layout == "obs" else ((), (axis,))
+    placer = BlockPlacer(rows, mesh, obs, feat, num_features=cols)
+    score = MIScore(num_values=2, num_classes=2)
+    acc = _cached_acc_fn(score, placer, mesh, batch=q)
+
+    one = score.init_state(placer.padded_features, "feature")
+    state = jnp.zeros((q,) + one.shape, one.dtype)
+    if mesh is None:
+        st_sh = x_sh = t_sh = v_sh = one_chip
+    else:
+        o, f = obs[0] if obs else None, feat[0] if feat else None
+        st_sh = placer.state_shardings(state)
+        x_sh = NamedSharding(mesh, P(o, f))
+        t_sh = NamedSharding(mesh, P(None, o))
+        v_sh = NamedSharding(mesh, P(o))
+    shapes = [
+        jax.ShapeDtypeStruct(state.shape, state.dtype, sharding=st_sh),
+        jax.ShapeDtypeStruct(
+            (rows, placer.padded_features), jnp.int8, sharding=x_sh
+        ),
+        jax.ShapeDtypeStruct((q, rows), jnp.int8, sharding=t_sh),
+        jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=v_sh),
+    ]
+    compiled = acc.lower(*shapes).compile()
+    (out,) = jax.tree.leaves(compiled.out_info)
+    assert out.shape == state.shape and out.dtype == jnp.int32
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    if layout == "obs":
+        assert "all-reduce" in text  # the per-block psum of the counts
+
+
+@pytest.mark.parametrize(
     "layout,rows,cols,q,cond",
     [
         ("one", BLOCK, 1000, None, None),  # a mid redundancy target
