@@ -10,19 +10,16 @@ Then it times on device-resident blocks:
 
 * ``acc_pallas`` / ``acc_jnp``: the accumulate program each fit ran, as
   ``AccumulateLog`` recorded it (the engine's own program and layout);
-* ``kernel``: ``contingency_tables_pallas`` on an int32 block, i.e. the
-  accumulate without its int8 -> int32 widening and bookkeeping.
+* ``kernel``: ``contingency_tables_pallas`` on the same int8 block, i.e.
+  the accumulate without its target masking and state add.
 
 Each op is timed two ways.  ``device_us``: ``--iters`` and twice as many
 runs inside one jitted loop (one dispatch each), the difference divided
 by ``--iters`` — device time per block.  ``dispatch_us``: ``--iters``
 separate back-to-back calls (the accumulate chains its state), elapsed /
 iters — the per-block cost as the fit pays it, host dispatch included.
-HBM bytes per block are counted from the shapes (read + write), not
-measured.  The accumulate widens the int8 block to int32 ahead of the
-kernel; ``widened_block`` reports, from the compiled program, whether XLA
-placed that int32 copy in HBM or in on-chip VMEM (memory space 1), and the
-accumulate's HBM bytes count it only in HBM.  ``host_feed`` times one pass
+HBM bytes per block are counted from the shapes (one read of the int8
+block and of the target), not measured.  ``host_feed`` times one pass
 of the fit's input path without the count: read each block from the
 memmap, then ``device_put`` it and wait.
 
@@ -34,7 +31,6 @@ from __future__ import annotations
 import argparse
 import json
 import pathlib
-import re
 import shutil
 import statistics
 import time
@@ -136,16 +132,6 @@ def _device_s(op, block, carry, iters) -> list[float]:
     return out
 
 
-def _widened_memory_space(text: str, m: int, f: int) -> str | None:
-    """Where the compiled accumulate keeps its int32 copy of the (m, f)
-    block: "vmem" (memory space 1), "hbm", or None when the program has
-    no such convert (the CPU fuses it away)."""
-    hit = re.search(rf"= s32\[{m},{f}\]\{{([^}}]*)\}} convert\(", text)
-    if hit is None:
-        return None
-    return "vmem" if "S(1)" in hit.group(1) else "hbm"
-
-
 def _host_feed(source, x_sharding, y_sharding) -> dict:
     import jax
 
@@ -212,7 +198,7 @@ def main(argv=None) -> dict:
             )
         x_abs, y_abs = logs["pallas"].entries[0].args[1:3]
         m, f = x_abs.shape
-        X32 = _concrete(jax.ShapeDtypeStruct(x_abs.shape, jnp.int32), rng)
+        X8 = _concrete(x_abs, rng)
         y32 = jnp.asarray(rng.integers(0, 2, m), jnp.int32)
         interpret = dev.platform != "tpu"  # CPU rehearsals only
 
@@ -220,23 +206,13 @@ def main(argv=None) -> dict:
             return contingency_tables_pallas(X, y32, 2, 2, interpret=interpret)
 
         timed["kernel"] = (
-            _dispatch_s(jax.jit(count), (X32,), args.iters, False),
+            _dispatch_s(jax.jit(count), (X8,), args.iters, False),
             _device_s(
-                lambda c, b: c + count(b), X32,
+                lambda c, b: c + count(b), X8,
                 jnp.zeros((f, 2, 2), jnp.float32), args.iters,
             ),
         )
-        # The accumulate reads the int8 block and widens it to int32 for the
-        # kernel (64 MiB per 65536 x 256 block).  That copy costs HBM bytes
-        # (written, then read back by the kernel) only where XLA put it there.
-        widened = _widened_memory_space(
-            logs["pallas"].compiled_texts()[0], m, f
-        )
-        _emit(widened_block=widened)
-        hbm_bytes = dict(kernel=m * f * 4 + m * 4)
-        hbm_bytes["acc_pallas"] = m * f + m * 2 + (
-            2 * m * f * 4 if widened == "hbm" else 0
-        )
+        hbm_bytes = dict(kernel=m * f + m * 4, acc_pallas=m * f + m * 2)
         for name, (disp, devs) in timed.items():
             med = statistics.median(devs)
             nb = hbm_bytes.get(name)
@@ -254,7 +230,6 @@ def main(argv=None) -> dict:
         summary = dict(
             device=dict(platform=dev.platform, kind=dev.device_kind),
             rows=args.rows, cols=args.cols, blocks_per_fit=blocks,
-            widened_block=widened,
         )
         for name in scores:
             acc_s = statistics.median(timed[f"acc_{name}"][1]) * blocks

@@ -72,8 +72,9 @@ _DONE = object()
 _PASS_END = object()
 
 # Share of the smallest free device memory that one fit's resident blocks
-# may take.  The rest is left to the statistics state, each pass's widened
-# block and whatever else shares the devices (concurrent fits included).
+# may take.  The rest is left to the statistics state, each accumulate's
+# temporaries and whatever else shares the devices (concurrent fits
+# included).
 RESIDENT_FRACTION = 0.5
 
 # Bytes a row of a resident block's target is counted at: the widest a

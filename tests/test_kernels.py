@@ -11,7 +11,10 @@ import pytest
 
 from repro.kernels import ops, ref
 from repro.kernels.binning import bin_codes_pallas
-from repro.kernels.contingency import contingency_tables_pallas
+from repro.kernels.contingency import (
+    conditional_tables_pallas,
+    contingency_tables_pallas,
+)
 from repro.kernels.mi_score import mi_scores_pallas
 from repro.kernels.pearson import pearson_corr_pallas
 
@@ -52,14 +55,38 @@ class TestContingencyKernel:
         got = contingency_tables_pallas(X, y, 2, 2, interpret=True)
         np.testing.assert_allclose(got[0], np.array([[1, 0], [0, 1]]))
 
-    @pytest.mark.parametrize("dtype", [jnp.int8, jnp.int16, jnp.int32])
-    def test_dtypes(self, dtype):
+    @pytest.mark.parametrize(
+        "dtype,m,f,v,conditional",
+        [
+            pytest.param(jnp.int8, 40, 5, 2, False, id="int8"),
+            pytest.param(jnp.int16, 40, 5, 2, False, id="int16"),
+            pytest.param(jnp.int32, 40, 5, 2, False, id="int32"),
+            # X counted as it arrives, unpadded: ragged last feature tile
+            # (300 and 1000 at the default 256) and ragged last row tile.
+            pytest.param(jnp.int8, 1030, 300, 3, False, id="int8-1030x300"),
+            pytest.param(jnp.int16, 1030, 300, 3, False, id="int16-1030x300"),
+            pytest.param(jnp.int32, 1030, 300, 3, False, id="int32-1030x300"),
+            pytest.param(jnp.int8, 2100, 1000, 2, False, id="int8-2100x1000"),
+            pytest.param(jnp.int8, 1030, 300, 3, True, id="cond-int8-1030x300"),
+            pytest.param(jnp.int16, 1030, 300, 3, True, id="cond-int16-1030x300"),
+            pytest.param(jnp.int32, 1030, 300, 3, True, id="cond-int32-1030x300"),
+            pytest.param(jnp.int8, 1100, 1000, 2, True, id="cond-int8-1100x1000"),
+        ],
+    )
+    def test_dtypes(self, dtype, m, f, v, conditional):
         rng = np.random.default_rng(1)
-        X = jnp.asarray(rng.integers(0, 2, (40, 5)), dtype)
-        y = jnp.asarray(rng.integers(0, 2, 40), dtype)
-        got = contingency_tables_pallas(X, y, 2, 2, interpret=True)
-        want = ref.contingency_tables(X.astype(jnp.int32), y.astype(jnp.int32), 2, 2)
-        np.testing.assert_allclose(got, want, atol=0)
+        X = jnp.asarray(rng.integers(0, v, (m, f)), dtype)
+        y = jnp.asarray(rng.integers(0, 2, m), dtype)
+        X32, y32 = X.astype(jnp.int32), y.astype(jnp.int32)
+        if conditional:
+            xj = jnp.asarray(rng.integers(0, v, m), dtype)
+            got = conditional_tables_pallas(X, xj, y, v, 2, interpret=True)
+            want = ref.conditional_tables(X32, xj.astype(jnp.int32), y32, v, 2)
+        else:
+            got = contingency_tables_pallas(X, y, v, 2, interpret=True)
+            want = ref.contingency_tables(X32, y32, v, 2)
+        assert got.shape == want.shape
+        np.testing.assert_array_equal(got, want)
 
 
 class TestPearsonKernel:

@@ -12,6 +12,7 @@ process at a time may load the TPU library.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -75,6 +76,7 @@ def _kernel_case(name):
             "contingency_v3": (256, 3, 2),
             "contingency_v16": (64, 16, 2),
             "contingency_v32": (256, 32, 2),
+            "contingency_v2_f1000": (1000, 2, 2),  # a ragged last tile
         }[name]
         return (
             lambda X, y: contingency_tables_pallas(X, y, v, c),
@@ -101,6 +103,7 @@ def _kernel_case(name):
         "contingency_v3",
         "contingency_v16",
         "contingency_v32",
+        "contingency_v2_f1000",
         "conditional_v2",
         "conditional_v16",
         "conditional_v32",
@@ -158,6 +161,56 @@ def test_sharded_accumulate_compiles_for_2x2(
     assert "tpu_custom_call" in text
     if layout == "obs":
         assert "all-reduce" in text  # the per-block psum of the counts
+
+
+@pytest.mark.parametrize(
+    "layout,rows,cols",
+    [("one", BLOCK, 1000), ("one", 2048, 50000), ("obs", BLOCK, 1000)],
+)
+def test_accumulate_counts_the_int8_block(
+    layout, rows, cols, topo, one_chip, no_persistent_cache, monkeypatch
+):
+    # The count kernel reads the placed int8 block itself: no widened,
+    # relaid or padded int32 copy of it is made in HBM ahead of the
+    # kernel, at the benchmark's block shapes.
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    if layout == "one":
+        mesh, obs, st_sh = None, (), one_chip
+        x_sh = t_sh = one_chip
+    else:
+        mesh = Mesh(
+            np.asarray(topo.devices).reshape(4), ("data",),
+            axis_types=(AxisType.Auto,),
+        )
+        obs = ("data",)
+        x_sh = NamedSharding(mesh, P("data", None))
+        t_sh = NamedSharding(mesh, P("data"))
+    placer = BlockPlacer(rows, mesh, obs, (), num_features=cols)
+    score = MIScore(num_values=2, num_classes=2)
+    acc = _cached_acc_fn(score, placer, mesh)
+    state = score.init_state(placer.padded_features)
+    if mesh is not None:
+        st_sh = placer.state_shardings(state)
+    shapes = [
+        jax.ShapeDtypeStruct(state.shape, state.dtype, sharding=st_sh),
+        jax.ShapeDtypeStruct((rows, cols), jnp.int8, sharding=x_sh),
+        jax.ShapeDtypeStruct((rows,), jnp.int8, sharding=t_sh),
+        jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=t_sh),
+    ]
+    text = acc.lower(*shapes).compile().as_text()
+    local_rows = rows // (4 if mesh is not None else 1)
+    kernels = re.findall(
+        r'custom_call_target="tpu_custom_call", '
+        r"operand_layout_constraints=\{(\w+)\[(\d+),(\d+)\]", text
+    )
+    assert kernels == [("s8", str(local_rows), str(cols))]
+    widened = [
+        line for line in text.splitlines()
+        if (m := re.search(r"= s32\[(\d+),(\d+)\]\S* (\w+)\(", line))
+        and int(m[1]) == local_rows and int(m[2]) >= cols
+        and m[3] in ("pad", "convert", "copy", "fusion")
+    ]
+    assert not widened, widened
 
 
 @pytest.mark.parametrize(
